@@ -381,7 +381,8 @@ class CompileOracle(SettingsOracle):
         spec = WorkerSpec(
             factory="repro.compiler.oracle:_compile_measure_factory",
             kwargs={"arch": arch, "shape": shape, "verbose": verbose},
-            env={"XLA_FLAGS": _pinned_xla_flags(n_devices)})
+            env={"XLA_FLAGS": _pinned_xla_flags(n_devices),
+                 "JAX_PLATFORMS": "cpu"})
         own = executor is None
         if executor is None and self.workers > 0:
             executor = SubprocessExecutor(spec, workers=self.workers,

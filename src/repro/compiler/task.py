@@ -100,7 +100,10 @@ class TuningTask:
             # The pod mesh needs the placeholder device count pinned *before*
             # jax initializes (same dance as repro.launch.autotune's import);
             # a no-op if the backend is already up — hence the check below.
+            # The platform is pinned too: the placeholders are CPU devices,
+            # and an attached chip must stay free for other processes.
             import os
+            os.environ["JAX_PLATFORMS"] = "cpu"
             if "--xla_force_host_platform_device_count" not in \
                     os.environ.get("XLA_FLAGS", ""):
                 os.environ["XLA_FLAGS"] = (

@@ -1,11 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels (GEMM, flash attention, RMSNorm) and their jnp oracles."""
+import jax
 
-# Shared jax-version compat: jax renamed pltpu.TPUCompilerParams ->
-# pltpu.CompilerParams; kernel modules take the alias from here so the
-# next rename is one edit.
-from jax.experimental.pallas import tpu as _pltpu
 
-_CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    _pltpu.TPUCompilerParams
+def interpret_mode() -> bool:
+    """Pallas interpret mode for the default backend: Mosaic on a TPU, the
+    interpreter on the CPU (tests). Any other backend is an error rather
+    than a silent, slow interpreter run."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels need a TPU (Mosaic) or the CPU (interpret mode); "
+        f"the default backend is {backend!r}")

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _CompilerParams
 
 _NEG_INF = -1e30
 _MINLANE = 128  # scratch minor dim (TPU lane width)
@@ -140,7 +139,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, _MINLANE), jnp.float32),
             pltpu.VMEM((block_q, _MINLANE), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

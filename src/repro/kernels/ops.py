@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Backend dispatch: on TPU the kernels compile through Mosaic; anywhere else
-(this CPU container) they execute under ``interpret=True`` so tests validate
-the exact kernel bodies.  ``use_pallas=False`` falls back to the jnp oracle —
-that path is what the 512-device dry-run lowers (Pallas does not partition
+Backend dispatch: on TPU the kernels compile through Mosaic; on the CPU
+(tests) they execute under ``interpret=True`` so tests validate the exact
+kernel bodies; any other backend raises (``interpret_mode``).
+``use_pallas=False`` falls back to the jnp oracle — that path is what the 512-device dry-run lowers (Pallas does not partition
 across GSPMD meshes; the kernels are the per-core fast path).
 """
 from __future__ import annotations
@@ -14,13 +14,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import interpret_mode, ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.gemm import GemmConfig, gemm as _gemm, gemm_config_from_knobs
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("config", "use_pallas"))
@@ -29,7 +25,7 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray,
            use_pallas: bool = True) -> jnp.ndarray:
     if not use_pallas:
         return ref.matmul_ref(a, b)
-    return _gemm(a, b, config, interpret=_interpret())
+    return _gemm(a, b, config, interpret=interpret_mode())
 
 
 def im2col(x: jnp.ndarray, kh: int, kw: int, stride: int, pad: int
@@ -62,7 +58,7 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, pad: int = 0,
     kh, kw, ci, co = w.shape
     patches, (oh, ow) = im2col(x, kh, kw, stride, pad)
     out = _gemm(patches, w.reshape(kh * kw * ci, co), config,
-                interpret=_interpret())
+                interpret=interpret_mode())
     return out.reshape(b, oh, ow, co)
 
 
@@ -88,4 +84,5 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if not use_pallas:
         return ref.attention_ref(q, k, v, causal=causal, window=window)
     return _flash(q, k, v, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, interpret=_interpret())
+                  block_q=block_q, block_k=block_k,
+                  interpret=interpret_mode())

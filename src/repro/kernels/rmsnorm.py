@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _CompilerParams
+from repro.kernels import interpret_mode
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -30,7 +30,7 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
             ) -> jnp.ndarray:
     """x: (..., d), w: (d,)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     shape = x.shape
     d = shape[-1]
     rows = 1
@@ -52,7 +52,7 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, w)

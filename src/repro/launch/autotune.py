@@ -1,4 +1,6 @@
 import os
+# placeholder CPU devices stand in for the pod: never touch an attached chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "--xla_force_host_platform_device_count" not in \
         os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")

@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_EXTRA", "") +
                            " --xla_force_host_platform_device_count="
                            + os.environ.get("REPRO_DRYRUN_DEVICES", "512")
@@ -7,6 +8,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_XLA_EXTRA", "") +
 #   initialization.  512 placeholder host devices stand in for 2 pods x 256
 #   TPU v5e chips; lowering/compiling against them proves the distribution
 #   config (shardings, collectives, memory) is coherent without hardware.
+#   The platform is pinned to the CPU so an attached chip is never touched.
 
 # Multi-pod dry-run driver.
 #
@@ -150,8 +152,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 if v is not None:
                     result[k] = int(v)
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else None
         if cost:
             result["cost_flops"] = float(cost.get("flops", 0.0))
             result["cost_bytes"] = float(cost.get("bytes accessed", 0.0))

@@ -5,14 +5,22 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes. JAX 0.9 defaults to
+    ``Explicit`` axes, which the model code (GSPMD-style, sharded by
+    ``with_sharding_constraint`` and jit in/out shardings) is not written
+    for: the first embedding gather fails with ``ShardingTypeError``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """The production mesh: 16x16 (data, model) per pod; 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_dryrun_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -26,20 +34,20 @@ def make_dryrun_mesh(*, multi_pod: bool = False) -> Mesh:
         model = max(1, int(per_pod ** 0.5))
         while per_pod % model:
             model -= 1
-        return jax.make_mesh((2, per_pod // model, model),
-                             ("pod", "data", "model"))
+        return _auto_mesh((2, per_pod // model, model),
+                          ("pod", "data", "model"))
     model = max(1, int(n ** 0.5))
     while n % model:
         model -= 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_host_mesh(data: int = 1, model: int = 1,
                    pod: Optional[int] = None) -> Mesh:
     """Small mesh over however many (host) devices exist — for tests."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def describe(mesh: Mesh) -> str:

@@ -25,11 +25,13 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.train.server import Request, Server
 
@@ -61,7 +63,7 @@ def _warm_up(srv: Server, vocab: int) -> None:
     srv.run_until_drained(max_steps=64)
 
 
-def main():
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="continuous-batching LM server over synthetic "
                     "requests, with optional online geometry tuning")
@@ -90,16 +92,63 @@ def main():
                          "for the duration of the run (0 = ephemeral)")
     ap.add_argument("--json-out", metavar="PATH", default=None,
                     help="also write the report JSON here")
-    args = ap.parse_args()
+    return ap
+
+
+def build_server(args) -> Tuple[T.ArchConfig, Server]:
+    """Random weights from ``--seed``, a ``Server`` over them, warmed up."""
+    cfg = get_config(args.arch, reduced=args.reduced)
+    params = T.init_params(jax.random.PRNGKey(args.seed), cfg)
+    srv = Server(params, cfg, n_slots=args.slots, max_len=args.max_len)
+    _warm_up(srv, cfg.vocab)
+    return cfg, srv
+
+
+def serve_drain(srv: Server, cfg: T.ArchConfig, args,
+                prompt_lens: Optional[Sequence[int]] = None
+                ) -> Tuple[dict, List[Request]]:
+    """Submit ``--requests`` requests up front and serve until drained.
+    Prompt lengths are drawn from 4..23, or cycle through ``prompt_lens``
+    (a fixed set bounds the prefill programs compiled, one per length).
+    Returns the report fields and the finished requests."""
+    rng = np.random.default_rng(args.seed)
+    t0 = time.perf_counter()
+    for i in range(args.requests):
+        n = (prompt_lens[i % len(prompt_lens)] if prompt_lens
+             else int(rng.integers(4, 24)))
+        srv.submit(Request(
+            uid=i,
+            prompt=rng.integers(0, cfg.vocab, size=n).astype(np.int32),
+            max_new_tokens=args.max_new))
+    done = srv.run_until_drained()
+    dt = time.perf_counter() - t0
+    toks = sum(len(r.output) for r in done)
+    doc = {
+        "requests": len(done),
+        "generated_tokens": toks,
+        "wall_s": round(dt, 3),
+        "tokens_per_sec": round(toks / dt, 1),
+        "rejected": len(srv.rejected),
+        "abandoned": len(srv.abandoned),
+    }
+    doc.update(_latency_stats(done))
+    if done:
+        lats = np.asarray([r.latency_s for r in done])
+        doc["violation_pct"] = round(float(
+            100.0 * (lats > args.sla_ms / 1e3).mean()), 3)
+    return doc, done
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    ap = make_parser()
+    args = ap.parse_args(argv)
     if args.autotune and args.rate <= 0:
         ap.error("--autotune needs --rate > 0: tuning measures in the "
                  "idle gaps between arrivals, and a fully up-front queue "
                  "has none")
 
-    cfg = get_config(args.arch, reduced=args.reduced)
-    params = T.init_params(jax.random.PRNGKey(args.seed), cfg)
-    srv = Server(params, cfg, n_slots=args.slots, max_len=args.max_len)
-    _warm_up(srv, cfg.vocab)
+    enable_compile_cache()
+    cfg, srv = build_server(args)
 
     doc = {"arch": cfg.name, "sla_ms": args.sla_ms}
     if args.autotune or args.rate > 0:
@@ -140,31 +189,7 @@ def main():
         })
         doc.update(_latency_stats(done))
     else:
-        rng = np.random.default_rng(args.seed)
-        t0 = time.perf_counter()
-        for i in range(args.requests):
-            srv.submit(Request(
-                uid=i,
-                prompt=rng.integers(
-                    0, cfg.vocab,
-                    size=int(rng.integers(4, 24))).astype(np.int32),
-                max_new_tokens=args.max_new))
-        done = srv.run_until_drained()
-        dt = time.perf_counter() - t0
-        toks = sum(len(r.output) for r in done)
-        doc.update({
-            "requests": len(done),
-            "generated_tokens": toks,
-            "wall_s": round(dt, 3),
-            "tokens_per_sec": round(toks / dt, 1),
-            "rejected": len(srv.rejected),
-            "abandoned": len(srv.abandoned),
-        })
-        doc.update(_latency_stats(done))
-        if done:
-            lats = np.asarray([r.latency_s for r in done])
-            doc["violation_pct"] = round(float(
-                100.0 * (lats > args.sla_ms / 1e3).mean()), 3)
+        doc.update(serve_drain(srv, cfg, args)[0])
 
     # loud, unmissable: these were never served and are NOT in the stats
     for kind, reqs in (("rejected", srv.rejected),

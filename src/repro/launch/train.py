@@ -12,17 +12,17 @@ from __future__ import annotations
 import argparse
 import json
 import time
-
-import jax
+from typing import Optional, Sequence, Tuple
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train.steps import TrainConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
 
-def main():
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_NAMES, default="smollm-360m")
     ap.add_argument("--reduced", action="store_true",
@@ -36,8 +36,13 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--data", type=int, default=1, help="data mesh axis")
     ap.add_argument("--model", type=int, default=1, help="model mesh axis")
-    args = ap.parse_args()
+    return ap
 
+
+def run(args) -> Tuple[Trainer, dict]:
+    """Train ``--steps`` steps on a ``--data`` x ``--model`` mesh of the
+    host's devices, resuming from the newest checkpoint under ``--ckpt``;
+    returns the trainer and a summary (wall time includes compilation)."""
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = make_host_mesh(args.data, args.model)
     tc = TrainConfig(lr=args.lr, warmup_steps=max(args.steps // 20, 1),
@@ -57,13 +62,18 @@ def main():
     log = trainer.run()
     dt = time.time() - t0
     losses = [e for e in log if "loss" in e]
-    print(json.dumps({"first_loss": losses[0]["loss"],
-                      "last_loss": losses[-1]["loss"],
-                      "steps": trainer.step,
-                      "wall_s": round(dt, 1),
-                      "tokens_per_s": round(
-                          trainer.step * args.batch * args.seq / dt)},
-                     indent=1))
+    return trainer, {"first_loss": losses[0]["loss"],
+                     "last_loss": losses[-1]["loss"],
+                     "steps": trainer.step,
+                     "wall_s": round(dt, 1),
+                     "tokens_per_s": round(
+                         trainer.step * args.batch * args.seq / dt)}
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    args = make_parser().parse_args(argv)
+    enable_compile_cache()
+    print(json.dumps(run(args)[1], indent=1))
 
 
 if __name__ == "__main__":

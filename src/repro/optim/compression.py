@@ -35,10 +35,7 @@ def compressed_psum_mean(grads: Any, err: Any, axis_names,
     n = 1
     for a in (axis_names if isinstance(axis_names, (tuple, list))
               else (axis_names,)):
-        # jax.lax.axis_size is missing from older jax; psum(1) is the
-        # version-stable way to read a mapped axis size under shard_map
-        n = n * (jax.lax.axis_size(a) if hasattr(jax.lax, "axis_size")
-                 else jax.lax.psum(1, a))
+        n = n * jax.lax.axis_size(a)
 
     def one(g, e):
         g = g.astype(jnp.float32) + e

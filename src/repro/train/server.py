@@ -108,6 +108,11 @@ class Server:
             lambda p, b: T.prefill(p, b, cfg, max_len),
             static_argnums=())
 
+    def prefill_programs(self) -> int:
+        """Prefill programs compiled so far: prefill is jitted on the exact
+        prompt shape, so one per distinct prompt length."""
+        return self._prefill._cache_size()
+
     # ------------------------------------------------------------- intake
     def submit(self, req: Request) -> Request:
         """Queue ``req`` (stamping its end-to-end latency clock), or fail

@@ -174,6 +174,10 @@ class Trainer:
                     self.step += 1
                     if self.step % self.trc.ckpt_every == 0:
                         self._save()
+                except jax.errors.JaxRuntimeError:
+                    # device OOM / compile error: a restart would only
+                    # recompile and fail again, so raise at once
+                    raise
                 except (RuntimeError, FloatingPointError) as e:
                     self.restarts += 1
                     if self.restarts > self.trc.max_restarts:

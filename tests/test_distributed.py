@@ -138,6 +138,31 @@ def test_trainer_loss_decreases_and_survives_faults(tmp_path):
     assert last < first, (first, last)       # still learning after recovery
 
 
+def test_trainer_raises_device_errors_without_rollback(tmp_path):
+    """A device or compile error (JaxRuntimeError, a RuntimeError) would
+    only fail again after a restore: it raises at once, unretried."""
+    from repro.configs import get_config
+    from repro.data.pipeline import DataConfig as DC
+    from repro.launch.mesh import make_host_mesh
+    from repro.train.steps import TrainConfig
+    from repro.train.trainer import FailureInjector, Trainer, TrainerConfig
+
+    class DeviceFault(FailureInjector):
+        def maybe_fail(self, step, batch):
+            if step == 1:
+                raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: hbm")
+            return batch
+
+    cfg = get_config("smollm-360m", reduced=True)
+    trc = TrainerConfig(steps=3, ckpt_dir=str(tmp_path), ckpt_every=1)
+    tr = Trainer(cfg, TrainConfig(lr=1e-3), trc, make_host_mesh(1, 1),
+                 data_cfg=DC(vocab=cfg.vocab, seq_len=16, global_batch=2),
+                 injector=DeviceFault())
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        tr.run()
+    assert tr.restarts == 0 and tr.step == 1
+
+
 def test_trainer_restart_resumes_from_checkpoint(tmp_path):
     from repro.configs import get_config
     from repro.launch.mesh import make_host_mesh

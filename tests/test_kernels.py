@@ -181,3 +181,19 @@ def test_rmsnorm_kernel(shape, block_rows):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref_rmsnorm(x, w)),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------------- backend dispatch
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Mosaic on a TPU, the interpreter on the CPU, an error elsewhere:
+    no backend silently falls back to the slow interpreter."""
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            interpret_mode()
+    else:
+        assert interpret_mode() is interpret

@@ -4,6 +4,7 @@
         --shape train_4k --multipod --min-gb 0.3
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
 import argparse
