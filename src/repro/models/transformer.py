@@ -211,22 +211,23 @@ def _apply_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
     aux = jnp.zeros((), jnp.float32)
     cache: Dict[str, Any] = {}
     if mixer in ("attn", "swa"):
-        window = cfg.swa_window if mixer == "swa" else None
-        b, s, _ = x.shape
-        h = L.rmsnorm(x, p["mix"]["ln"])
-        q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
-            .reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = L.dense(h, p["mix"]["wk"], p["mix"].get("bk")) \
-            .reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        v = L.dense(h, p["mix"]["wv"], p["mix"].get("bv")) \
-            .reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        if cfg.use_rope:
-            q = L.rope(q, positions, cfg.rope_theta)
-            k = L.rope(k, positions, cfg.rope_theta)
-        out = L.chunked_attention(q, k, v, causal=causal, window=window,
-                                  chunk=cfg.attn_chunk)
-        x = x + L.dense(out.reshape(b, s, -1), p["mix"]["wo"])
-        cache["k"], cache["v"] = k, v
+        with jax.named_scope("attention"):
+            window = cfg.swa_window if mixer == "swa" else None
+            b, s, _ = x.shape
+            h = L.rmsnorm(x, p["mix"]["ln"])
+            q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
+                .reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = L.dense(h, p["mix"]["wk"], p["mix"].get("bk")) \
+                .reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = L.dense(h, p["mix"]["wv"], p["mix"].get("bv")) \
+                .reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            if cfg.use_rope:
+                q = L.rope(q, positions, cfg.rope_theta)
+                k = L.rope(k, positions, cfg.rope_theta)
+            out = L.chunked_attention(q, k, v, causal=causal, window=window,
+                                      chunk=cfg.attn_chunk)
+            x = x + L.dense(out.reshape(b, s, -1), p["mix"]["wo"])
+            cache["k"], cache["v"] = k, v
     elif mixer == "mamba":
         x, st = SSM.mamba_block(x, p["mix"], cfg)
         cache["ssm"] = st
@@ -241,10 +242,11 @@ def _apply_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
         x = L.attention_block(x, p["cross"], cfg, positions, causal=False,
                               cross_kv=enc_kv)
 
-    if ffn == "moe":
-        x, aux = MOE.moe_block(x, p["ffn"], cfg)
-    elif ffn in ("mlp", "gelu"):
-        x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu")
+    with jax.named_scope("mlp"):
+        if ffn == "moe":
+            x, aux = MOE.moe_block(x, p["ffn"], cfg)
+        elif ffn in ("mlp", "gelu"):
+            x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu")
     return x, aux, cache
 
 
@@ -307,7 +309,8 @@ def embed_inputs(params: Params, batch: Dict[str, jnp.ndarray],
                  cfg: ArchConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Token/frontend embedding. Returns (x (B,S,D), positions (B,S))."""
     tokens = batch["tokens"]
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     if cfg.vision_prefix:
         patches = batch["patches"].astype(cfg.dtype)   # (B, P, D) stub
         x = jnp.concatenate([patches, x], axis=1)
@@ -338,7 +341,9 @@ def hidden_states(params: Params, batch: Dict[str, jnp.ndarray],
     x, aux, caches = _run_stack(x, params["layers"], cfg, cfg.pattern,
                                 positions, causal=True, enc_out=enc_out,
                                 collect_cache=collect_cache)
-    return L.rmsnorm(x, params["final_ln"]), aux, caches, enc_out
+    with jax.named_scope("lm_head"):
+        h = L.rmsnorm(x, params["final_ln"])
+    return h, aux, caches, enc_out
 
 
 @jax.custom_vjp
@@ -407,7 +412,9 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray],
         b = labels.shape[0]
         pad = jnp.full((b, cfg.vision_prefix), -1, labels.dtype)
         labels = jnp.concatenate([pad, labels], axis=1)
-    nll, cnt = chunked_xent(h, params["lm_head"], labels, cfg.loss_chunk)
+    with jax.named_scope("lm_head"):
+        nll, cnt = chunked_xent(h, params["lm_head"], labels,
+                                cfg.loss_chunk)
     loss = nll / jnp.maximum(cnt, 1.0)
     total = loss + cfg.aux_loss_weight * aux / max(cfg.n_layers, 1)
     return total, {"nll": loss, "aux": aux, "tokens": cnt}
@@ -476,30 +483,32 @@ def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
     """One-token block. x: (B,1,D). Returns (x, updated cache entry)."""
     new = dict(entry)
     if mixer in ("attn", "swa"):
-        b = x.shape[0]
-        window = cfg.swa_window if mixer == "swa" else None
-        ring = (mixer == "swa" and cfg.swa_window is not None
-                and entry["k"].shape[1] <= cfg.swa_window)
-        h = L.rmsnorm(x, p["mix"]["ln"])
-        q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
-            .reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        k = L.dense(h, p["mix"]["wk"], p["mix"].get("bk")) \
-            .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = L.dense(h, p["mix"]["wv"], p["mix"].get("bv")) \
-            .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        if cfg.use_rope:
-            pp = jnp.broadcast_to(jnp.reshape(pos, (-1, 1))
-                                  if jnp.ndim(pos) else pos, (b, 1))
-            q = L.rope(q, pp, cfg.rope_theta)
-            k = L.rope(k, pp, cfg.rope_theta)
-        kc, vc = L.update_kv_cache(entry["k"], entry["v"], k, v, pos,
-                                   ring=ring)
-        if ring:
-            out = L.decode_attention_ring(q, kc, vc, pos, cfg.swa_window)
-        else:
-            out = L.decode_attention(q, kc, vc, pos + 1, window=window)
-        x = x + L.dense(out.reshape(b, 1, -1), p["mix"]["wo"])
-        new["k"], new["v"] = kc, vc
+        with jax.named_scope("attention"):
+            b = x.shape[0]
+            window = cfg.swa_window if mixer == "swa" else None
+            ring = (mixer == "swa" and cfg.swa_window is not None
+                    and entry["k"].shape[1] <= cfg.swa_window)
+            h = L.rmsnorm(x, p["mix"]["ln"])
+            q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
+                .reshape(b, 1, cfg.n_heads, cfg.head_dim)
+            k = L.dense(h, p["mix"]["wk"], p["mix"].get("bk")) \
+                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            v = L.dense(h, p["mix"]["wv"], p["mix"].get("bv")) \
+                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            if cfg.use_rope:
+                pp = jnp.broadcast_to(jnp.reshape(pos, (-1, 1))
+                                      if jnp.ndim(pos) else pos, (b, 1))
+                q = L.rope(q, pp, cfg.rope_theta)
+                k = L.rope(k, pp, cfg.rope_theta)
+            with jax.named_scope("kv_update"):
+                kc, vc = L.update_kv_cache(entry["k"], entry["v"], k, v,
+                                           pos, ring=ring)
+            if ring:
+                out = L.decode_attention_ring(q, kc, vc, pos, cfg.swa_window)
+            else:
+                out = L.decode_attention(q, kc, vc, pos + 1, window=window)
+            x = x + L.dense(out.reshape(b, 1, -1), p["mix"]["wo"])
+            new["k"], new["v"] = kc, vc
     elif mixer == "mamba":
         x, st = SSM.mamba_block(x, p["mix"], cfg, SSM.MambaState(*entry["ssm"]),
                                 decode=True)
@@ -522,10 +531,11 @@ def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
                                  jnp.asarray(cfg.enc_seq, jnp.int32))
         x = x + L.dense(out.reshape(b, 1, -1), p["cross"]["wo"])
 
-    if ffn == "moe":
-        x, _ = MOE.moe_block(x, p["ffn"], cfg)
-    elif ffn in ("mlp", "gelu"):
-        x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu")
+    with jax.named_scope("mlp"):
+        if ffn == "moe":
+            x, _ = MOE.moe_block(x, p["ffn"], cfg)
+        elif ffn in ("mlp", "gelu"):
+            x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu")
     return x, new
 
 
@@ -533,7 +543,8 @@ def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
                 cfg: ArchConfig) -> Tuple[jnp.ndarray, Params]:
     """One decode step. tokens: (B, 1) -> (logits (B, V), new cache)."""
     pos = cache["pos"]
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
 
     def body(x, slices):
         layer_params, entries = slices
@@ -544,10 +555,13 @@ def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
             new_entries.append(new)
         return x, tuple(new_entries)
 
+    # the scan itself stays unscoped: its slicing and stacking of the
+    # cache leaves is told apart from the blocks' own work that way
     x, new_layers = jax.lax.scan(body, x, (params["layers"],
                                            cache["layers"]))
-    h = L.rmsnorm(x, params["final_ln"])
-    logits = logits_last(params, h, cfg)
+    with jax.named_scope("lm_head"):
+        h = L.rmsnorm(x, params["final_ln"])
+        logits = logits_last(params, h, cfg)
     return logits, {"pos": pos + 1, "layers": new_layers}
 
 
@@ -561,21 +575,23 @@ def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ArchConfig,
     for pidx, (mixer, _) in enumerate(cfg.pattern):
         entry = dict(caches[pidx]) if caches is not None else {}
         if mixer in ("attn", "swa"):
-            c = _cache_seq_len(cfg, mixer, max_len)
-            k, v = entry.pop("k"), entry.pop("v")          # (R,B,S,KV,Dh)
-            if c >= s:
-                padw = ((0, 0), (0, 0), (0, c - s), (0, 0), (0, 0))
-                entry["k"] = jnp.pad(k, padw)
-                entry["v"] = jnp.pad(v, padw)
-            else:  # ring: keep the last c tokens, rotated so that
-                   # slot (s % c) is the oldest (next write target)
-                k, v = k[:, :, s - c:], v[:, :, s - c:]
-                shift = s % c
-                idx = (jnp.arange(c) - shift) % c
-                entry["k"] = k[:, :, idx]
-                entry["v"] = v[:, :, idx]
+            with jax.named_scope("attention"), jax.named_scope("kv_update"):
+                c = _cache_seq_len(cfg, mixer, max_len)
+                k, v = entry.pop("k"), entry.pop("v")          # (R,B,S,KV,Dh)
+                if c >= s:
+                    padw = ((0, 0), (0, 0), (0, c - s), (0, 0), (0, 0))
+                    entry["k"] = jnp.pad(k, padw)
+                    entry["v"] = jnp.pad(v, padw)
+                else:  # ring: keep the last c tokens, rotated so that
+                       # slot (s % c) is the oldest (next write target)
+                    k, v = k[:, :, s - c:], v[:, :, s - c:]
+                    shift = s % c
+                    idx = (jnp.arange(c) - shift) % c
+                    entry["k"] = k[:, :, idx]
+                    entry["v"] = v[:, :, idx]
         layers.append(entry)
-    logits = logits_last(params, h, cfg)
+    with jax.named_scope("lm_head"):
+        logits = logits_last(params, h, cfg)
     b = h.shape[0]
     return logits, {"pos": jnp.full((b,), s, jnp.int32),
                     "layers": tuple(layers)}

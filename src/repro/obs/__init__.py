@@ -9,6 +9,11 @@ decomposes into measure vs surrogate-refit vs mappo-update vs
 executor-wait — per phase, per endpoint — instead of being one opaque
 number.
 
+The model paths emit into the same tracer: ``Server.step`` and
+``Trainer.run`` open ``serve.*`` and ``train.*`` spans, and a tracer built
+with ``annotate=jax.profiler.TraceAnnotation`` puts those spans on the
+device trace's clock.
+
 Design constraints, in order:
 
 * **Near-zero cost when off.**  The ambient tracer defaults to a shared

@@ -17,13 +17,18 @@ lookup per span site and nothing else (guarded by a tier-1 overhead
 test).  ``use()`` is re-entrant; a ``Session`` run *inside* an active
 netopt trace inherits the outer tracer because a session without its own
 ``trace=``/``obs=`` never overrides the ambient one.
+
+A tracer built with ``annotate=`` also enters ``annotate(name, **args)``
+around each span.  A JAX caller passes ``jax.profiler.TraceAnnotation``,
+so the same spans land in the profiler's host plane, on the clock of the
+device's operations; this module itself never imports JAX.
 """
 from __future__ import annotations
 
 import random
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from repro.obs.metrics import Metrics, NoopMetrics
 
@@ -37,7 +42,8 @@ class _SpanHandle:
     """Context manager for one open span; re-used per call, not pooled —
     span entry/exit only happens on instrumented (non-noop) runs."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  tid: Optional[str], args: Optional[dict]) -> None:
@@ -48,6 +54,11 @@ class _SpanHandle:
         self._args = args
 
     def __enter__(self) -> "_SpanHandle":
+        annotate = self._tracer.annotate
+        self._ann = (None if annotate is None
+                     else annotate(self._name, **(self._args or {})))
+        if self._ann is not None:
+            self._ann.__enter__()
         self._tracer._stack().append(self._name)
         self._t0 = time.monotonic()
         return self
@@ -58,6 +69,8 @@ class _SpanHandle:
         stack.pop()
         self._tracer._record(self._name, self._cat, self._t0, dur,
                              self._tid, self._args, depth=len(stack))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -67,16 +80,20 @@ class Tracer:
     Internal event rows are plain dicts with monotonic-seconds
     timestamps; :mod:`repro.obs.export` converts them to Chrome-trace
     microseconds.  ``metrics`` is a full :class:`Metrics` registry that
-    rides along into the export's ``otherData``.
+    rides along into the export's ``otherData``.  ``annotate`` is an
+    optional ``(name, **args) -> context manager`` entered around every
+    span (see the module docstring).
     """
 
     def __init__(self, name: str = "repro", sample_rate: float = 1.0,
-                 sample_seed: int = 0) -> None:
+                 sample_seed: int = 0,
+                 annotate: Optional[Callable[..., Any]] = None) -> None:
         if not 0.0 <= sample_rate <= 1.0:
             raise ValueError(f"sample_rate must be in [0, 1], "
                              f"got {sample_rate}")
         self.name = name
         self.enabled = True
+        self.annotate = annotate
         # wall-clock seconds at monotonic zero: wall = epoch + monotonic
         self.epoch = time.time() - time.monotonic()
         self.metrics = Metrics()

@@ -17,6 +17,13 @@ chunk of background work per call — e.g. one candidate measurement of an
 online tuning session, see :mod:`repro.compiler.serve_tune`) runs only when
 the queue is empty and at least one decode slot is free, so live requests
 always preempt background work at chunk granularity.
+
+Each ``step()`` emits ``repro.obs`` spans into the ambient tracer
+(``serve.step`` and, inside it, ``serve.admit`` per request with its
+``serve.prefill`` / ``serve.insert`` / ``serve.first_token``, then
+``serve.best_effort``, ``serve.decode``, ``serve.decode_sync`` and
+``serve.emit``).  The spans sit at the host syncs the step already has
+and add none; under the default no-op tracer each costs one call.
 """
 from __future__ import annotations
 
@@ -29,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import transformer as T
 
 # Request.status values, in lifecycle order.
@@ -102,11 +110,15 @@ class Server:
         self.rejected: List[Request] = []
         self.abandoned: List[Request] = []
         self.best_effort = best_effort
-        self._decode = decode_fn or jax.jit(
-            lambda p, c, t: T.decode_step(p, c, t, cfg), donate_argnums=(1,))
-        self._prefill = jax.jit(
-            lambda p, b: T.prefill(p, b, cfg, max_len),
-            static_argnums=())
+
+        def serve_decode(p, c, t):
+            return T.decode_step(p, c, t, cfg)
+
+        def serve_prefill(p, b):
+            return T.prefill(p, b, cfg, max_len)
+
+        self._decode = decode_fn or jax.jit(serve_decode, donate_argnums=(1,))
+        self._prefill = jax.jit(serve_prefill)
 
     def prefill_programs(self) -> int:
         """Prefill programs compiled so far: prefill is jitted on the exact
@@ -135,24 +147,33 @@ class Server:
         self.queue.append(req)
         return req
 
-    def _admit(self):
+    def _admit(self, tr) -> None:
+        """Prefill and slot every queued request a free slot takes."""
         while self.free and self.queue:
             req = self.queue.popleft()
             slot = self.free.pop()
-            req.admit_s = time.perf_counter()
-            req.queue_s = req.admit_s - req.submit_s
-            batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32)}
-            if self.cfg.vision_prefix:
-                batch["patches"] = jnp.zeros(
-                    (1, self.cfg.vision_prefix, self.cfg.d_model),
-                    self.cfg.dtype)
-            if self.cfg.enc_dec:
-                batch["frames"] = jnp.zeros(
-                    (1, self.cfg.enc_seq, self.cfg.d_model), self.cfg.dtype)
-            logits, rc = self._prefill(self.params, batch)
-            self.cache = _insert_slot(self.cache, rc, slot)
-            first = int(jnp.argmax(logits[0]))   # also syncs the prefill
-            req.prefill_s = time.perf_counter() - req.admit_s
+            with tr.span("serve.admit", uid=req.uid,
+                         prompt_len=len(req.prompt)):
+                req.admit_s = time.perf_counter()
+                req.queue_s = req.admit_s - req.submit_s
+                batch = {"tokens": jnp.asarray(req.prompt[None, :],
+                                               jnp.int32)}
+                if self.cfg.vision_prefix:
+                    batch["patches"] = jnp.zeros(
+                        (1, self.cfg.vision_prefix, self.cfg.d_model),
+                        self.cfg.dtype)
+                if self.cfg.enc_dec:
+                    batch["frames"] = jnp.zeros(
+                        (1, self.cfg.enc_seq, self.cfg.d_model),
+                        self.cfg.dtype)
+                with tr.span("serve.prefill"):
+                    logits, rc = self._prefill(self.params, batch)
+                with tr.span("serve.insert"):
+                    self.cache = _insert_slot(self.cache, rc, slot)
+                with tr.span("serve.first_token"):
+                    # also syncs the prefill
+                    first = int(jnp.argmax(logits[0]))
+                req.prefill_s = time.perf_counter() - req.admit_s
             req.output = [first]
             req.status = ACTIVE
             self.last_tok[slot, 0] = first
@@ -189,25 +210,39 @@ class Server:
         With idle capacity (free slots + empty queue) one chunk of
         best-effort work runs first — alongside the decode when other
         slots are busy, or alone when the server is idle."""
-        self._admit()
-        self._tick_best_effort()
+        tr = obs.current()
+        with tr.span("serve.step"):
+            return self._step(tr)
+
+    def _step(self, tr) -> List[Request]:
+        self._admit(tr)
+        with tr.span("serve.best_effort"):
+            self._tick_best_effort()
         if not self.active:
             return []
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self.last_tok))
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
+        # the slots decoded and the positions they attend, summed
+        args = {} if not tr.enabled else {
+            "active": len(self.active),
+            "context": sum(len(r.prompt) + len(r.output)
+                           for r in self.active.values())}
+        with tr.span("serve.decode", **args):
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self.last_tok))
+        with tr.span("serve.decode_sync"):
+            toks = np.asarray(jnp.argmax(logits, axis=-1))
         done: List[Request] = []
-        for slot, req in list(self.active.items()):
-            t = int(toks[slot])
-            req.output.append(t)
-            self.last_tok[slot, 0] = t
-            self.new_counts[slot] += 1
-            ended = (req.eos_id is not None and t == req.eos_id)
-            full = (self.new_counts[slot] >= req.max_new_tokens)
-            too_long = (len(req.prompt) + self.new_counts[slot]
-                        >= self.max_len - 1)
-            if ended or full or too_long:
-                done.append(self._finish(slot))
+        with tr.span("serve.emit"):
+            for slot, req in list(self.active.items()):
+                t = int(toks[slot])
+                req.output.append(t)
+                self.last_tok[slot, 0] = t
+                self.new_counts[slot] += 1
+                ended = (req.eos_id is not None and t == req.eos_id)
+                full = (self.new_counts[slot] >= req.max_new_tokens)
+                too_long = (len(req.prompt) + self.new_counts[slot]
+                            >= self.max_len - 1)
+                if ended or full or too_long:
+                    done.append(self._finish(slot))
         return done
 
     def run_until_drained(self, max_steps: int = 10000) -> List[Request]:

@@ -53,7 +53,7 @@ def train_step_fn(cfg: T.ArchConfig, tc: TrainConfig
     """
     opt = make_optimizer(tc)
 
-    def step(params, opt_state: AdamState, batch):
+    def train_step(params, opt_state: AdamState, batch):
         if tc.grad_accum > 1:
             def micro(carry, mb):
                 gacc, lacc = carry
@@ -81,7 +81,7 @@ def train_step_fn(cfg: T.ArchConfig, tc: TrainConfig
                        grad_norm=_global_norm(grads))
         return params, opt_state, metrics
 
-    return step
+    return train_step
 
 
 def _global_norm(tree):
@@ -92,17 +92,17 @@ def _global_norm(tree):
 def serve_step_fn(cfg: T.ArchConfig) -> Callable:
     """f(params, cache, tokens(B,1)) -> (logits (B,V), cache)."""
 
-    def step(params, cache, tokens):
+    def serve_decode(params, cache, tokens):
         return T.decode_step(params, cache, tokens, cfg)
 
-    return step
+    return serve_decode
 
 
 def prefill_fn(cfg: T.ArchConfig, max_len: int) -> Callable:
-    def step(params, batch):
+    def serve_prefill(params, batch):
         return T.prefill(params, batch, cfg, max_len)
 
-    return step
+    return serve_prefill
 
 
 # --------------------------------------------------------------------------

@@ -13,6 +13,11 @@ Failure model (what actually happens on big pods) and the response here:
                                    production from the step cadence.
 
 ``FailureInjector`` lets tests script crashes/NaNs deterministically.
+
+``Trainer.run`` emits ``repro.obs`` spans into the ambient tracer: a
+``train.step`` per step holding ``train.data`` (the prefetch hand-over),
+``train.dispatch``, ``train.loss_sync`` (the ``float(loss)`` the loop
+already waits on) and ``train.ckpt``.
 """
 from __future__ import annotations
 
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.dist import sharding as SH
 from repro.models import transformer as T
@@ -147,33 +153,15 @@ class Trainer:
         return self._step_fn
 
     def run(self) -> List[Dict[str, float]]:
-        self._save(sync=True)  # step-0 anchor
+        tr = obs.current()
+        with tr.span("train.ckpt"):
+            self._save(sync=True)  # step-0 anchor
         prefetch = Prefetcher(self.ds, start_step=self.step)
         try:
             while self.step < self.trc.steps:
                 try:
-                    batch = prefetch.next()
-                    if self.injector:
-                        batch = self.injector.maybe_fail(self.step, batch)
-                    t0 = time.perf_counter()
-                    with self.mesh:
-                        fn = self._compiled_step(batch)
-                        self.params, self.opt_state, metrics = fn(
-                            self.params, self.opt_state, batch)
-                    loss = float(metrics["loss"])
-                    if (self.step % self.trc.nan_check_every == 0
-                            and not math.isfinite(loss)):
-                        raise FloatingPointError(
-                            f"non-finite loss at step {self.step}: {loss}")
-                    dt = time.perf_counter() - t0
-                    if self.step % self.trc.log_every == 0:
-                        self.metrics_log.append(
-                            {"step": self.step, "loss": loss,
-                             "grad_norm": float(metrics["grad_norm"]),
-                             "sec": dt})
-                    self.step += 1
-                    if self.step % self.trc.ckpt_every == 0:
-                        self._save()
+                    with tr.span("train.step", step=self.step):
+                        self._one_step(tr, prefetch)
                 except jax.errors.JaxRuntimeError:
                     # device OOM / compile error: a restart would only
                     # recompile and fail again, so raise at once
@@ -192,5 +180,33 @@ class Trainer:
         finally:
             prefetch.close()
             self.ckpt.wait()
-        self._save(sync=True)
+        with tr.span("train.ckpt"):
+            self._save(sync=True)
         return self.metrics_log
+
+    def _one_step(self, tr, prefetch: Prefetcher) -> None:
+        with tr.span("train.data"):
+            batch = prefetch.next()
+        if self.injector:
+            batch = self.injector.maybe_fail(self.step, batch)
+        t0 = time.perf_counter()
+        with tr.span("train.dispatch"), self.mesh:
+            fn = self._compiled_step(batch)
+            self.params, self.opt_state, metrics = fn(
+                self.params, self.opt_state, batch)
+        with tr.span("train.loss_sync"):
+            loss = float(metrics["loss"])
+        if (self.step % self.trc.nan_check_every == 0
+                and not math.isfinite(loss)):
+            raise FloatingPointError(
+                f"non-finite loss at step {self.step}: {loss}")
+        dt = time.perf_counter() - t0
+        if self.step % self.trc.log_every == 0:
+            self.metrics_log.append(
+                {"step": self.step, "loss": loss,
+                 "grad_norm": float(metrics["grad_norm"]),
+                 "sec": dt})
+        self.step += 1
+        if self.step % self.trc.ckpt_every == 0:
+            with tr.span("train.ckpt"):
+                self._save()

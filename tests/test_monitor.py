@@ -5,7 +5,7 @@ ports, source attach/finalize/freeze, broken-callback isolation, the
 ``active_servers()`` registry), the Prometheus text exposition, the
 span-sampling bookkeeping (dropped measure/dispatch seconds folded back
 exactly — never estimated — through both export forms), the
-``trace_diff`` and ``bench_compare`` regression gates, the metrics
+``bench_compare`` regression gate, the metrics
 edge cases (bucket quantiles, all three executor ``stats()`` shapes,
 concurrent counter increments), and the acceptance bar: a live netopt
 run over a loopback worker daemon whose final ``/metrics`` scrape
@@ -339,54 +339,6 @@ def test_recent_spans_tail_is_wall_anchored_and_bounded():
         assert s["dur_s"] >= 0.0
         assert abs(s["wall_s"] - now) < 60.0  # anchored to the wall clock
     assert obs.NOOP.recent_spans() == []
-
-
-# --------------------------------------------------------------- trace_diff
-
-def _write_trace(tmp_path, name, phase_s, measure_s):
-    tr = obs.Tracer(name="d")
-    tr.add_span_mono("phase:seed", cat="phase", start_mono_s=0.0,
-                     dur_s=phase_s)
-    tr.add_span_mono("measure", cat="measure", start_mono_s=0.0,
-                     dur_s=measure_s)
-    path = str(tmp_path / name)
-    tr.save(path)
-    return path
-
-
-def test_trace_diff_same_trace_passes_gate(tmp_path, capsys):
-    td = _load_tool("trace_diff")
-    old = _write_trace(tmp_path, "a.json", 1.0, 0.5)
-    new = _write_trace(tmp_path, "b.json", 1.0, 0.5)
-    assert td.main([old, new, "--fail-on-regression", "10"]) == 0
-    out = capsys.readouterr().out
-    assert "phase:seed" in out and "+0.0%" in out
-
-
-def test_trace_diff_flags_injected_slowdown(tmp_path, capsys):
-    td = _load_tool("trace_diff")
-    old = _write_trace(tmp_path, "a.json", 1.0, 0.5)
-    slow = _write_trace(tmp_path, "c.json", 1.6, 0.5)  # +60% in the phase
-    assert td.main([old, slow]) == 0  # report-only without the gate
-    capsys.readouterr()
-    assert td.main([old, slow, "--fail-on-regression", "25"]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "phase:seed" in out
-    rows = td.diff_rows({"p": 1.0}, {"p": 1.6, "q": 2.0})
-    assert rows == [("p", 1.0, 1.6, pytest.approx(60.0)),
-                    ("q", 0.0, 2.0, float("inf"))]
-    # brand-new rows (no old baseline) never fail the gate
-    assert td.regressions(rows, 25.0, 0.05) == [("p", 1.0, 1.6,
-                                                 pytest.approx(60.0))]
-
-
-def test_trace_diff_noise_floor_protects_tiny_rows(tmp_path):
-    td = _load_tool("trace_diff")
-    old = _write_trace(tmp_path, "a.json", 0.01, 0.002)
-    new = _write_trace(tmp_path, "b.json", 0.04, 0.004)  # +300%, all tiny
-    assert td.main([old, new, "--fail-on-regression", "25"]) == 0
-    assert td.main([old, new, "--fail-on-regression", "25",
-                    "--min-s", "0.001"]) == 1
 
 
 # ------------------------------------------------------------ bench_compare
