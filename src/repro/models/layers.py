@@ -306,24 +306,22 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 def update_kv_cache(k_cache: jnp.ndarray, v_cache: jnp.ndarray,
                     k_new: jnp.ndarray, v_new: jnp.ndarray,
-                    position: jnp.ndarray, ring: bool = False
+                    layer: jnp.ndarray, position: jnp.ndarray,
+                    ring: bool = False
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Write one token at ``position`` (scalar or per-sequence (B,)).
+    """Write one token per sequence into layer ``layer`` of stacked caches.
 
-    ``ring``: modulo wraparound (sliding-window caches store only the last
-    ``S_max`` tokens).  Per-sequence positions enable continuous batching —
-    each slot in the batch can be at a different decode depth.
+    caches: (R, B, S_max, HKV, D); new: (B, 1, HKV, D); ``position``: (B,),
+    so each slot in the batch can be at a different decode depth
+    (continuous batching).  ``ring``: modulo wraparound (sliding-window
+    caches store only the last ``S_max`` tokens).  One scatter per cache at
+    ``(layer, b, position[b])``, which XLA runs in place on a donated or
+    loop-carried cache; a position past ``S_max`` writes nothing.
     """
-    smax = k_cache.shape[1]
-    pos = jnp.asarray(position)
-    pos = pos % smax if ring else pos
-    if pos.ndim == 0:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new, pos, 1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new, pos, 1)
-        return k_cache, v_cache
-    upd = jax.vmap(lambda c, n, p:
-                   jax.lax.dynamic_update_slice_in_dim(c, n, p, 0))
-    return upd(k_cache, k_new, pos), upd(v_cache, v_new, pos)
+    b, smax = k_cache.shape[1], k_cache.shape[2]
+    pos = position % smax if ring else position
+    at = (layer, jnp.arange(b), pos)
+    return k_cache.at[at].set(k_new[:, 0]), v_cache.at[at].set(v_new[:, 0])
 
 
 def decode_attention_ring(q, k_cache, v_cache, position,

@@ -12,7 +12,9 @@ FFNs:    mlp  | moe | gelu  | none
 Three entry points (built by ``repro.train.steps``):
   train:   tokens -> chunked-softmax xent loss (never materializes B,S,V)
   prefill: tokens -> logits for the last position + a decode cache
-  decode:  one token + cache -> next-token logits + updated cache
+  decode:  one token + cache -> next-token logits + updated cache; the
+           layer scan carries the stacked cache, and each layer writes its
+           token in place and reads its K/V by index
 """
 from __future__ import annotations
 
@@ -479,15 +481,17 @@ def _dummy_mamba_params(cfg: ArchConfig):
 
 
 def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
-                  entry, pos):
-    """One-token block. x: (B,1,D). Returns (x, updated cache entry)."""
+                  entry, i, pos):
+    """One-token block of layer ``i``. x: (B,1,D); ``entry``: this period
+    position's cache, stacked over repeats. Returns (x, updated entry)."""
     new = dict(entry)
+    at = partial(jax.lax.dynamic_index_in_dim, index=i, keepdims=False)
     if mixer in ("attn", "swa"):
         with jax.named_scope("attention"):
             b = x.shape[0]
             window = cfg.swa_window if mixer == "swa" else None
             ring = (mixer == "swa" and cfg.swa_window is not None
-                    and entry["k"].shape[1] <= cfg.swa_window)
+                    and entry["k"].shape[2] <= cfg.swa_window)
             h = L.rmsnorm(x, p["mix"]["ln"])
             q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
                 .reshape(b, 1, cfg.n_heads, cfg.head_dim)
@@ -501,33 +505,32 @@ def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
                 q = L.rope(q, pp, cfg.rope_theta)
                 k = L.rope(k, pp, cfg.rope_theta)
             with jax.named_scope("kv_update"):
-                kc, vc = L.update_kv_cache(entry["k"], entry["v"], k, v,
-                                           pos, ring=ring)
+                new["k"], new["v"] = L.update_kv_cache(
+                    entry["k"], entry["v"], k, v, i, pos, ring=ring)
+            # the layer's K/V read from the written leaves, so they hold
+            # this token; XLA fuses the slice into the attention reads
+            kc, vc = at(new["k"]), at(new["v"])
             if ring:
                 out = L.decode_attention_ring(q, kc, vc, pos, cfg.swa_window)
             else:
                 out = L.decode_attention(q, kc, vc, pos + 1, window=window)
             x = x + L.dense(out.reshape(b, 1, -1), p["mix"]["wo"])
-            new["k"], new["v"] = kc, vc
-    elif mixer == "mamba":
-        x, st = SSM.mamba_block(x, p["mix"], cfg, SSM.MambaState(*entry["ssm"]),
-                                decode=True)
-        new["ssm"] = st
-    elif mixer == "mlstm":
-        x, st = SSM.mlstm_block(x, p["mix"], cfg, SSM.LstmState(*entry["lstm"]),
-                                decode=True)
-        new["lstm"] = st
-    elif mixer == "slstm":
-        x, st = SSM.slstm_block(x, p["mix"], cfg,
-                                SSM.SlstmState(*entry["slstm"]), decode=True)
-        new["slstm"] = st
+    elif mixer in ("mamba", "mlstm", "slstm"):
+        key, state, block = {
+            "mamba": ("ssm", SSM.MambaState, SSM.mamba_block),
+            "mlstm": ("lstm", SSM.LstmState, SSM.mlstm_block),
+            "slstm": ("slstm", SSM.SlstmState, SSM.slstm_block)}[mixer]
+        x, st = block(x, p["mix"], cfg, state(*map(at, entry[key])),
+                      decode=True)
+        # recurrent states are small: each is written back whole
+        new[key] = jax.tree.map(lambda s, n: s.at[i].set(n), entry[key], st)
 
     if cfg.enc_dec and "cross" in p:
         b = x.shape[0]
         h = L.rmsnorm(x, p["cross"]["ln"])
         q = L.dense(h, p["cross"]["wq"]) \
             .reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        out = L.decode_attention(q, entry["xk"], entry["xv"],
+        out = L.decode_attention(q, at(entry["xk"]), at(entry["xv"]),
                                  jnp.asarray(cfg.enc_seq, jnp.int32))
         x = x + L.dense(out.reshape(b, 1, -1), p["cross"]["wo"])
 
@@ -546,19 +549,23 @@ def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
 
-    def body(x, slices):
-        layer_params, entries = slices
+    def body(carry, xs):
+        x, entries = carry
+        layer_params, i = xs
         new_entries = []
         for pidx, (mixer, ffn) in enumerate(cfg.pattern):
             x, new = _decode_block(x, layer_params[pidx], cfg, mixer, ffn,
-                                   entries[pidx], pos)
+                                   entries[pidx], i, pos)
             new_entries.append(new)
-        return x, tuple(new_entries)
+        return (x, tuple(new_entries)), None
 
-    # the scan itself stays unscoped: its slicing and stacking of the
-    # cache leaves is told apart from the blocks' own work that way
-    x, new_layers = jax.lax.scan(body, x, (params["layers"],
-                                           cache["layers"]))
+    # the stacked cache rides in the carry and each layer indexes it by
+    # ``i``, so a donated cache is written in place, one token per slot,
+    # and no layer's cache is sliced out or stacked back. The scan stays
+    # unscoped.
+    (x, new_layers), _ = jax.lax.scan(
+        body, (x, cache["layers"]),
+        (params["layers"], jnp.arange(cfg.repeats)))
     with jax.named_scope("lm_head"):
         h = L.rmsnorm(x, params["final_ln"])
         logits = logits_last(params, h, cfg)

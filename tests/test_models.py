@@ -6,6 +6,7 @@ import pytest
 
 from repro.configs import ARCH_NAMES, all_configs, get_config
 from repro.configs.shapes import SHAPES, cell_supported, input_specs
+from repro.models import layers as L
 from repro.models import transformer as T
 
 
@@ -97,6 +98,74 @@ def test_swa_ring_cache_long_decode():
                                       cfg)
         np.testing.assert_allclose(np.asarray(lr), np.asarray(lb),
                                    rtol=2e-3, atol=1e-3)
+
+
+def _decode_by_layer(params, cache, tokens, cfg):
+    """One decode step, layer by layer in Python, each block given its
+    layer's cache sliced out as a stack of one."""
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    rows = []
+    for r in range(cfg.repeats):
+        row = []
+        for pidx, (mixer, ffn) in enumerate(cfg.pattern):
+            p = jax.tree.map(lambda a: a[r], params["layers"][pidx])
+            entry = jax.tree.map(lambda a: a[r:r + 1], cache["layers"][pidx])
+            x, new = T._decode_block(x, p, cfg, mixer, ffn, entry, 0,
+                                     cache["pos"])
+            row.append(new)
+        rows.append(row)
+    layers = tuple(jax.tree.map(lambda *a: jnp.concatenate(a),
+                                *[row[pidx] for row in rows])
+                   for pidx in range(cfg.period))
+    logits = T.logits_last(params, L.rmsnorm(x, params["final_ln"]), cfg)
+    return logits, layers
+
+
+@pytest.mark.parametrize("arch,n_layers,max_len,pos", [
+    ("qwen2-1.5b", 4, 16, [3, 10, 0, 15]),          # dense attention
+    ("mixtral-8x22b", 4, 16, [5, 17, 30, 16]),      # SWA ring, wrapped
+    ("jamba-1.5-large-398b", 16, 16, [3, 10, 0, 7]),  # mamba + attention
+])
+def test_decode_step_writes_only_each_slots_position(arch, n_layers,
+                                                     max_len, pos):
+    """Per-slot positions that differ: the step writes K/V at (layer, slot,
+    pos[slot] mod S) and leaves every other cache entry bit-identical, and
+    its logits and cache match the step computed layer by layer."""
+    cfg = get_config(arch, reduced=True).with_(
+        n_layers=n_layers, remat=False, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    b = len(pos)
+    cache = T.init_cache(cfg, b, max_len)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    cache = {"pos": jnp.asarray(pos, jnp.int32),
+             "layers": jax.tree.map(
+                 lambda a: jax.random.normal(next(keys), a.shape, a.dtype),
+                 cache["layers"])}
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (b, 1), 0, cfg.vocab)
+    logits, new = T.decode_step(params, cache, tokens, cfg)
+    ref_logits, ref_layers = _decode_by_layer(params, cache, tokens, cfg)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-5)
+    for got, want in zip(jax.tree.leaves(new["layers"]),
+                         jax.tree.leaves(ref_layers)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    n_kv = 0
+    for mixer, old, now in zip((m for m, _ in cfg.pattern), cache["layers"],
+                               new["layers"]):
+        if mixer not in ("attn", "swa"):
+            continue
+        for name in ("k", "v"):
+            was, got = np.asarray(old[name]), np.asarray(now[name])
+            written = np.zeros(was.shape[:3], bool)
+            written[:, np.arange(b), np.asarray(pos) % was.shape[2]] = True
+            np.testing.assert_array_equal(got[~written], was[~written])
+            assert (got[written] != was[written]).all()
+            n_kv += 1
+    assert n_kv == 2
+    np.testing.assert_array_equal(np.asarray(new["pos"]),
+                                  np.asarray(pos) + 1)
 
 
 def test_param_counts_full_configs():

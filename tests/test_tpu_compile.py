@@ -1,4 +1,5 @@
-"""The Pallas kernels compiled for a described TPU v5e at model widths.
+"""The Pallas kernels, and the decode step, compiled for a described TPU
+v5e at model widths.
 
 Nothing runs: each test compiles one kernel for a chip of a ``v5e:2x2``
 topology described by the installed TPU compiler, and checks that the
@@ -11,15 +12,18 @@ one process at a time may load the TPU library, and the pytest-xdist
 workers import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import gemm, gemm_config_from_knobs
 from repro.kernels.rmsnorm import rmsnorm
+from repro.models import transformer as T
 
 
 @pytest.fixture(scope="module")
@@ -87,3 +91,28 @@ def test_rmsnorm_compiles_to_mosaic(one_chip, d_model):
     w = _arg((d_model,), jnp.bfloat16, one_chip)
     text = _compiled_text(lambda x, w: rmsnorm(x, w, interpret=False), x, w)
     assert "tpu_custom_call" in text
+
+
+def test_decode_step_writes_the_donated_cache_in_place(one_chip):
+    """qwen2-1.5b widths at 2 layers, 8 slots x 1024 positions: with the
+    cache donated, the layer scan allocates no stacked leaf and copies
+    none; its scratch memory is below one layer's K."""
+    cfg = get_config("qwen2-1.5b").with_(n_layers=2)
+    slots, max_len = 8, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _arg(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(T.abstract_params(jax.random.PRNGKey(0), cfg))
+    cache = on_chip(jax.eval_shape(lambda: T.init_cache(cfg, slots, max_len)))
+    tokens = _arg((slots, 1), jnp.int32, one_chip)
+    compiled = jax.jit(lambda p, c, t: T.decode_step(p, c, t, cfg),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  tokens).compile()
+    leaf = cache["layers"][0]["k"]
+    layer_k_bytes = leaf.size // leaf.shape[0] * leaf.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k_bytes
+    stacked = ",".join(map(str, leaf.shape))
+    assert not re.search(rf"=\s*\w+\[{stacked}\]\S*\s+"
+                         rf"(copy\(|custom-call\(.*AllocateBuffer)",
+                         compiled.as_text())
