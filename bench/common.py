@@ -8,7 +8,7 @@ import os
 import shutil
 import tempfile
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 
@@ -64,6 +64,14 @@ class Check:
         return self.value <= self.limit
 
 
+def judged(readings: dict, limits: dict) -> dict:
+    """A control's readings held to the cell's committed limits, as the
+    harness holds the program's: ``correct`` is whether every limited
+    number keeps its limit (a control has to come out false)."""
+    checks = [Check(k, readings[k], limits[k]) for k in sorted(limits)]
+    return dict(readings, correct=all(k.ok for k in checks))
+
+
 @dataclasses.dataclass
 class Run:
     """What a driver hands back: counts, end-to-end metrics, the records
@@ -96,12 +104,17 @@ class SubTrace:
     window opened as it goes, and :meth:`finish` once the window and its
     drain are over: only then is the trace stopped, written and reduced
     into ``run.trace`` (stopping takes seconds, which inside the window
-    would stall the traffic). The device keeps the first events of the
-    trace when its buffer fills, which a part of ``MAX_S`` stays within."""
+    would stall the traffic), with device time split by the model family's
+    named ``scopes``. The device keeps the first events of the trace when
+    its buffer fills, which a part of ``MAX_S`` stays within. The
+    profiler's Python tracer is off: the ``bench.*`` annotations are host
+    events of their own, and no reading needs a trace of every Python
+    call."""
     START, LENGTH, MAX_S = 0.4, 0.5, 10.0
 
-    def __init__(self, on: bool, run: Run, seconds: float):
-        self.on, self.run = on, run
+    def __init__(self, on: bool, run: Run, seconds: float,
+                 scopes: Sequence[str] = ()):
+        self.on, self.run, self.scopes = on, run, tuple(scopes)
         self.t0 = self.START * seconds
         self.t1 = self.t0 + min(self.LENGTH * seconds, self.MAX_S)
         self.dir = self.mark = None
@@ -112,7 +125,9 @@ class SubTrace:
         if self.dir is None and elapsed >= self.t0:
             t = time.perf_counter()
             self.dir = tempfile.mkdtemp(prefix="bench_trace_")
-            jax.profiler.start_trace(self.dir)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.dir, profiler_options=opts)
             self.mark = jax.profiler.TraceAnnotation("bench.window")
             self.mark.__enter__()
             self.run.notes["trace_start_s"] = time.perf_counter() - t
@@ -127,11 +142,13 @@ class SubTrace:
             self.mark.__exit__(None, None, None)
         t = time.perf_counter()
         jax.profiler.stop_trace()
+        self.run.notes["trace_stop_s"] = time.perf_counter() - t
         from bench import trace_reduce
         try:
-            self.run.trace = trace_reduce.reduce_dir(self.dir)
+            self.run.trace = trace_reduce.reduce_dir(self.dir, self.scopes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
         self.run.notes["trace_stop_and_reduce_s"] = time.perf_counter() - t
         self.run.notes["idle_by_host_span_s"] = self.run.trace[
             "idle_by_host_span"]
+        self.run.notes["programs"] = self.run.trace["programs"]

@@ -11,6 +11,9 @@ precision below the configuration's, in the program's place, read against
 the float32 reference the same way, and whether they keep the committed
 limits (``correct``, which has to come out false).
 
+The cell's driver (``bench/drivers/<path>.py``) reads its controls with
+its ``control(run, seed, limits)``:
+
 - serve (bfloat16 weights): the int8 control ranks the tokens at each
   position of the same sequences the run served and checked, and its
   readings are those of what it ranks first (an fp8 control is read
@@ -26,6 +29,7 @@ run this.
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -36,43 +40,6 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 import jax  # noqa: E402
 
 from bench import run as bench_run  # noqa: E402
-from bench.common import Check  # noqa: E402
-from bench.drivers import serve as serve_driver  # noqa: E402
-from bench.drivers import train as train_driver  # noqa: E402
-from bench.reference import dense  # noqa: E402
-
-
-def judged(readings: dict, limits: dict) -> dict:
-    """A control's readings held to the cell's committed limits, as the
-    harness holds the program's: ``correct`` is whether every limited
-    number keeps its limit (a control has to come out false)."""
-    checks = [Check(k, readings[k], limits[k]) for k in sorted(limits)]
-    return dict(readings, correct=all(k.ok for k in checks))
-
-
-def control_serve(run, seed: int, limits: dict) -> dict:
-    cmp = run.compared
-    out = {"program": serve_driver.gap_readings(cmp["gaps"])}
-    for mode in ("int8", "fp8"):
-        out[mode] = judged(serve_driver.gap_readings(dense.served_logit_gaps(
-            cmp["spec"], seed, cmp["seqs"], cmp["pad_to"],
-            dtype=cmp["dtype"], mode=mode)), limits)
-    return out
-
-
-def control_train(run, seed: int, limits: dict) -> dict:
-    cmp = run.compared
-    ref = cmp["reference"]
-    out = {}
-    low = dense.train_reference(cmp["spec"], seed, cmp["batches"],
-                                cmp["opt"], mode="bf16", dtype=cmp["dtype"])
-    out["bf16"] = judged(train_driver.readings(low, ref), limits)
-    half = [(t[: len(t) // 2], lab[: len(lab) // 2])
-            for t, lab in cmp["batches"]]
-    fault = dense.train_reference(cmp["spec"], seed, half, cmp["opt"],
-                                  mode="f32", dtype=cmp["dtype"])
-    out["half_batch"] = judged(train_driver.readings(fault, ref), limits)
-    return out
 
 
 def main(argv=None) -> None:
@@ -89,8 +56,8 @@ def main(argv=None) -> None:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     controls = {int(s) for s in args.control_seeds.split(",") if s}
-    control = (control_serve if c["config"]["path"] == "serve"
-               else control_train)
+    control = importlib.import_module(
+        f"bench.drivers.{c['config']['path']}").control
     for seed in map(int, args.seeds.split(",")):
         run, setup_s = bench_run.execute(c, seed, args.seconds, False)
         line = {"seed": seed, "program": {k.name: k.value

@@ -12,9 +12,11 @@ after the last arrival until every request has finished; ``saturated``
 keeps the queue ``queue_depth`` deep until the window closes, then drains
 what it admitted. Each output token is stamped at the return of the
 ``step()`` that produced it; a request's first-token time runs from its
-scheduled arrival. The mean first-token time is the end-to-end metric; its
-tail is read beside it (``server.ttft_p95_ms``), because the p95 of ~120
-requests swings with how many arrivals share a step's prefills.
+scheduled arrival. In an open loop the mean first-token time is the
+end-to-end metric; its tail is read beside it (``server.ttft_p95_ms``),
+because the p95 of ~120 requests swings with how many arrivals share a
+step's prefills. A saturated queue's end-to-end metric is the tokens per
+second it completes, ``batch_tokens_per_s``.
 
 After the window the server's arrays are freed and the float32 reference
 re-runs a sample of the finished requests (the longest among them), one
@@ -23,6 +25,7 @@ the reference's best.
 """
 from __future__ import annotations
 
+import gc
 import time
 from typing import Dict, List
 
@@ -30,11 +33,16 @@ import jax
 import numpy as np
 
 from bench import program, traffic
-from bench.common import Check, Run, SubTrace, memory_peak_bytes
-from bench.reference import dense
+from bench.common import Check, Run, SubTrace, judged, memory_peak_bytes
 from repro.train.server import DONE, Request, Server
 
 DRAIN_LIMIT_S = 120.0   # serving after the close before a request is failed
+# the tokens per second of the window, by mode: an open loop's is bounded by
+# its offered load, a saturated queue's is the server's capacity
+RATE = {"open_loop": "serve_tokens_per_s", "saturated": "batch_tokens_per_s"}
+# sequences of ``max_len`` positions the reference runs at once: 12 of 4096
+# fit on a 16 GB chip once the server is freed
+REF_BLOCK = 12
 
 
 class _Live:
@@ -83,6 +91,41 @@ def _submit(srv: Server, live: Dict[int, _Live], p: traffic.Planned,
         req = srv.submit(Request(uid=p.uid, prompt=p.prompt,
                                  max_new_tokens=p.max_new))
     live[p.uid] = _Live(req, due, now)
+
+
+class _GcLog:
+    """The garbage collector's pauses while it is registered: where a run
+    reads low, whether the host stopped in the collector."""
+
+    def __init__(self):
+        self.t0, self.pauses = None, []
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self.t0 = time.perf_counter()
+        elif self.t0 is not None:
+            self.pauses.append((info["generation"],
+                                time.perf_counter() - self.t0))
+            self.t0 = None
+
+
+def _stalls(steps: list, gcs: _GcLog) -> dict:
+    """Where the window's time went beyond the usual step: the longest
+    ``step()``, the longest gap between two, and the collector's pauses."""
+    if not steps:
+        return {}
+    dur = np.array([s["t1"] - s["t0"] for s in steps])
+    gap = np.array([b["t0"] - a["t1"] for a, b in zip(steps, steps[1:])]
+                   or [0.0])
+    i = int(dur.argmax())
+    return {"step_ms_p50": 1e3 * float(np.median(dur)),
+            "step_ms_max": 1e3 * float(dur[i]),
+            "step_max_at_s": steps[i]["t0"],
+            "steps_over_3x_p50": int((dur > 3 * np.median(dur)).sum()),
+            "host_gap_ms_max": 1e3 * float(gap.max()),
+            "gc_ms_total": 1e3 * sum(t for _, t in gcs.pauses),
+            "gc_ms_max": 1e3 * max((t for _, t in gcs.pauses), default=0.0),
+            "gc_gen2": sum(1 for g, _ in gcs.pauses if g == 2)}
 
 
 def _busy(srv: Server) -> bool:
@@ -158,10 +201,11 @@ def gap_readings(gaps) -> Dict[str, float]:
 
 def run(ctx) -> Run:
     c, mix, seed, seconds = ctx.config, ctx.mix, ctx.seed, ctx.seconds
+    fam = program.family(c)
     cfg = program.arch_config(c, reduced=ctx.reduced)
-    spec = program.spec_of(cfg, c["tie_word_embeddings"])
+    spec = fam.spec_of(c, cfg)
     geo = c["serve"]
-    params = program.make_params(cfg, seed, spec.tied)
+    params = program.make_params(c, cfg, seed)
     srv = Server(params, cfg, n_slots=geo["slots"], max_len=geo["max_len"])
     _warm(srv, traffic.exercised_lengths(mix, seconds), cfg.vocab)
     if mix["mode"] == "open_loop":
@@ -174,7 +218,9 @@ def run(ctx) -> Run:
     live: Dict[int, _Live] = {}
     steps: List[dict] = []
     compiles0 = ctx.compile_log.n
-    tracer = SubTrace(ctx.trace, run, seconds)
+    tracer = SubTrace(ctx.trace, run, seconds, fam.SCOPES)
+    gcs = _GcLog()
+    gc.callbacks.append(gcs)
     ctx.open_window()
     if mix["mode"] == "open_loop":
         t_open = _open_loop(srv, plan, seconds, live, steps, tracer)
@@ -182,6 +228,7 @@ def run(ctx) -> Run:
         t_open = _saturated(srv, gen, int(mix["queue_depth"]), seconds,
                             live, steps, tracer)
     t_end = time.perf_counter()
+    gc.callbacks.remove(gcs)
     tracer.finish()
     compiles = ctx.compile_log.n - compiles0
     run.memory_peak_bytes = memory_peak_bytes()
@@ -197,7 +244,7 @@ def run(ctx) -> Run:
     run.metrics = {
         "ttft_mean_ms": 1e3 * float(np.mean(ttft)),
         "itl_p95_ms": 1e3 * float(np.percentile(gaps, 95)),
-        "serve_tokens_per_s": _tokens_in_window(live, t_close) / seconds,
+        RATE[mix["mode"]]: _tokens_in_window(live, t_close) / seconds,
     }
     run.requests = [{"queue_s": r.req.queue_s, "prefill_s": r.req.prefill_s,
                      "ttft_s": t, "prompt": len(r.req.prompt),
@@ -213,7 +260,7 @@ def run(ctx) -> Run:
         "ttft_ms": {f"p{q}": 1e3 * float(np.percentile(ttft, q))
                     for q in (50, 90, 95)},
         "prefill_programs": srv.prefill_programs(), "steps": len(steps),
-        "window_compiles": compiles})
+        "window_compiles": compiles, "stalls": _stalls(run.steps, gcs)})
 
     # ---- correctness: free the server, then the reference in blocks
     for a in jax.tree.leaves((srv.params, srv.cache)):
@@ -222,12 +269,10 @@ def run(ctx) -> Run:
     sample = _sample(finished, int(mix["check_requests"]), seed)
     seqs = [(r.req.prompt, np.asarray(r.req.output, np.int32))
             for r in sample]
-    run.compared = {"seqs": seqs, "spec": spec,
+    run.compared = {"family": fam, "seqs": seqs, "spec": spec,
                     "pad_to": geo["max_len"], "dtype": cfg.param_dtype}
     t_ref = time.perf_counter()
-    gaps_ref = dense.served_logit_gaps(
-        spec, seed, seqs, pad_to=geo["max_len"],
-        dtype=cfg.param_dtype)
+    gaps_ref = _reference_gaps(run.compared, seed)
     run.notes["reference_s"] = time.perf_counter() - t_ref
     run.compared["gaps"] = gaps_ref
     run.notes["checked_tokens"] = int(sum(len(g) for g in gaps_ref))
@@ -239,3 +284,26 @@ def run(ctx) -> Run:
                                  if k not in lim}
     return run
 
+
+def _reference_gaps(cmp: dict, seed: int, mode: str = "f32") -> list:
+    """The family's reference over the checked sequences, ``REF_BLOCK`` at
+    a time, so that its activations fit on the chip however many are
+    checked."""
+    seqs = cmp["seqs"]
+    return [g for i in range(0, len(seqs), REF_BLOCK)
+            for g in cmp["family"].served_logit_gaps(
+                cmp["spec"], seed, seqs[i:i + REF_BLOCK], cmp["pad_to"],
+                dtype=cmp["dtype"], mode=mode)]
+
+
+def control(run, seed: int, limits: dict) -> dict:
+    """The controls' readings on the sequences the run served and checked:
+    the int8 and fp8 references rank the tokens at each of their positions,
+    and the float32 reference reads what they rank first (``bench/control
+    .py``); the program's own readings beside them."""
+    cmp = run.compared
+    out = {"program": gap_readings(cmp["gaps"])}
+    for mode in ("int8", "fp8"):
+        out[mode] = judged(gap_readings(_reference_gaps(cmp, seed, mode)),
+                           limits)
+    return out

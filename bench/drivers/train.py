@@ -33,8 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import program
-from bench.common import Check, Run, SubTrace, memory_peak_bytes
-from bench.reference import dense
+from bench.common import Check, Run, SubTrace, judged, memory_peak_bytes
 from repro.data.pipeline import DataConfig
 from repro.launch.mesh import make_host_mesh
 from repro.train.steps import TrainConfig, make_optimizer
@@ -99,11 +98,11 @@ class Clock:
         return batch
 
 
-def _opt(geo: dict) -> dense.AdamW:
-    return dense.AdamW(lr=geo["lr"], warmup=geo["warmup_steps"],
-                       total=geo["total_steps"],
-                       weight_decay=geo["weight_decay"],
-                       clip_norm=geo["grad_clip"])
+def _opt(fam, geo: dict):
+    return fam.AdamW(lr=geo["lr"], warmup=geo["warmup_steps"],
+                     total=geo["total_steps"],
+                     weight_decay=geo["weight_decay"],
+                     clip_norm=geo["grad_clip"])
 
 
 def _worst_gap(prog: Dict[str, np.ndarray], ref: Dict[str, np.ndarray],
@@ -119,6 +118,7 @@ def _worst_gap(prog: Dict[str, np.ndarray], ref: Dict[str, np.ndarray],
 def run(ctx) -> Run:
     c, seed, seconds = ctx.config, ctx.seed, ctx.seconds
     geo = c["train"]
+    fam = program.family(c)
     cfg = program.arch_config(c, reduced=ctx.reduced)
     tc = TrainConfig(lr=geo["lr"], warmup_steps=geo["warmup_steps"],
                      total_steps=geo["total_steps"],
@@ -142,21 +142,21 @@ def run(ctx) -> Run:
     # the program's own initial state goes; the seeded one takes its place
     for a in jax.tree.leaves((trainer.params, trainer.opt_state)):
         a.delete()
-    trainer.params = jax.device_put(program.make_params(cfg, seed),
+    trainer.params = jax.device_put(program.make_params(c, cfg, seed),
                                     trainer.p_sh)
     trainer.opt_state = jax.device_put(
         make_optimizer(tc).init(trainer.params), trainer.o_sh)
     seen = {}
 
     def first_grad():      # before step 1: AdamW's state after step 0
-        mu = program.to_reference(trainer.opt_state.mu)
-        seen["grad"] = dense.leaf_norms(
+        mu = fam.to_reference(trainer.opt_state.mu)
+        seen["grad"] = fam.leaf_norms(
             jax.tree.map(lambda m: m.astype(jnp.float32) / (1 - B1), mu))
 
     clock.hooks[1] = first_grad
     trainer.run()
-    p0 = program.make_params(cfg, seed)      # made again: no copy in steps
-    change = dense.leaf_norms(program.to_reference(jax.tree.map(
+    p0 = program.make_params(c, cfg, seed)   # made again: no copy in steps
+    change = fam.leaf_norms(fam.to_reference(jax.tree.map(
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
         trainer.params, p0)))
     for a in jax.tree.leaves(p0):
@@ -167,7 +167,7 @@ def run(ctx) -> Run:
     run = Run()
     trainer.trc.steps = 10 ** 9
     compiles0 = ctx.compile_log.n
-    clock.tracer = SubTrace(ctx.trace, run, seconds)
+    clock.tracer = SubTrace(ctx.trace, run, seconds, fam.SCOPES)
     ctx.open_window()
     clock.seconds = seconds
     trainer.run()
@@ -190,12 +190,12 @@ def run(ctx) -> Run:
         a.delete()
     batches = clock.batches
     rows = [r.tobytes() for t, _ in batches for r in t]
-    run.compared = {"spec": program.spec_of(cfg), "batches": batches,
-                    "opt": _opt(geo), "dtype": cfg.param_dtype}
+    spec, opt = fam.spec_of(c, cfg), _opt(fam, geo)
+    run.compared = {"family": fam, "spec": spec, "batches": batches,
+                    "opt": opt, "dtype": cfg.param_dtype}
     t_ref = time.perf_counter()
-    ref_losses, ref_grad, ref_change = dense.train_reference(
-        program.spec_of(cfg), seed, batches, _opt(geo), mode="f32",
-        dtype=cfg.param_dtype)
+    ref_losses, ref_grad, ref_change = fam.train_reference(
+        spec, seed, batches, opt, mode="f32", dtype=cfg.param_dtype)
     run.notes["reference_s"] = time.perf_counter() - t_ref
     run.compared["reference"] = (ref_losses, ref_grad, ref_change)
     lim = run.compared["limits"] = c["limits"]
@@ -230,3 +230,20 @@ def leaves_that_move(ref_grad: Dict[str, np.ndarray]
     round-off alone."""
     med = np.median(np.concatenate(list(ref_grad.values())))
     return {k: v >= 1e-3 * med for k, v in ref_grad.items()}
+
+
+def control(run, seed: int, limits: dict) -> dict:
+    """The bfloat16 control following the run's three steps from the same
+    weights and rows, and beside it the fault of half the batch left out
+    (the mean over the first half) planted in the reference, each read
+    against the run's float32 reference (``bench/control.py``)."""
+    cmp = run.compared
+    fam, ref = cmp["family"], cmp["reference"]
+    low = fam.train_reference(cmp["spec"], seed, cmp["batches"], cmp["opt"],
+                              mode="bf16", dtype=cmp["dtype"])
+    half = [(t[: len(t) // 2], lab[: len(lab) // 2])
+            for t, lab in cmp["batches"]]
+    fault = fam.train_reference(cmp["spec"], seed, half, cmp["opt"],
+                                mode="f32", dtype=cmp["dtype"])
+    return {"bf16": judged(readings(low, ref), limits),
+            "half_batch": judged(readings(fault, ref), limits)}

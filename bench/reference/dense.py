@@ -22,6 +22,14 @@ Adam moments in bfloat16; ``int8`` quantizes weights per output channel
 and activations per token, symmetrically to 127 levels, before every
 product; ``fp8`` does the same onto float8 e4m3 with the largest value
 scaled to 448 (both simulated in float32).
+
+The module is also the harness's whole description of the family (the
+last section): the configuration file's ``reference`` key names it, and
+``bench/program.py`` takes from it the program's configuration, the
+reference spec, the two parameter layouts, the seeded weights, the checks'
+references and the operations and bytes each step needs. It reads the
+program's configuration object and parameter tree by their attribute and
+key names only.
 """
 from __future__ import annotations
 
@@ -469,3 +477,144 @@ def train_reference(spec: Dense, seed: int, batches, opt: AdamW,
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
         params, p0))
     return losses, g1, change
+
+
+# ---------------------------------------------------------------- family
+# What the harness runs for a configuration whose ``reference`` is this
+# module (``bench/program.py`` lists the names it takes).
+
+# the program's named scopes in this family's programs, innermost last
+# where they nest (``kv_update`` inside ``attention``)
+SCOPES = ("embed", "attention", "kv_update", "mlp", "lm_head")
+PATTERN = (("attn", "mlp"),)
+# program name -> reference name, per sub-tree of one layer
+MIX = {"ln": "attn_norm", "wq": "wq", "wk": "wk", "wv": "wv", "wo": "wo",
+       "bq": "bq", "bk": "bk", "bv": "bv"}
+FFN = {"ln": "mlp_norm", "w_gate": "w_gate", "w_up": "w_up",
+       "w_down": "w_down"}
+TOP = {"embed": "embed", "final_ln": "final_norm", "lm_head": "lm_head"}
+PROGRAM_RMS_EPS = 1e-6      # the program fixes its RMSNorm epsilon
+
+
+def arch_config(c: dict, cfg, reduced: bool):
+    """The program's configuration ``cfg`` for the file ``c``, with the
+    RoPE base the file states. Refuses a program configuration of another
+    family or block pattern, and (at full size) one whose sizes disagree
+    with the file."""
+    if cfg.family != "dense" or tuple(cfg.pattern) != PATTERN:
+        raise ValueError(
+            f"{c['name']}: the program runs {c['arch']} as family "
+            f"{cfg.family!r} with pattern {cfg.pattern}; {__name__} "
+            f"describes only a plain dense stack {PATTERN}")
+    cfg = cfg.with_(rope_theta=float(c["rope_theta"]))
+    if not reduced:
+        want = {"vocab": c["vocab_size"], "d_model": c["hidden_size"],
+                "n_layers": c["num_hidden_layers"],
+                "n_heads": c["num_attention_heads"],
+                "n_kv_heads": c["num_key_value_heads"],
+                "d_ff": c["intermediate_size"], "qkv_bias": c["qkv_bias"]}
+        got = {k: getattr(cfg, k) for k in want}
+        want["rms_norm_eps"] = c["rms_norm_eps"]
+        got["rms_norm_eps"] = PROGRAM_RMS_EPS
+        if got != want:
+            raise ValueError(f"{c['arch']}: the program runs {got}, the "
+                             f"configuration file states {want}")
+    return cfg
+
+
+def spec_of(c: dict, cfg) -> Dense:
+    """The reference's description of the model the program runs. A tied
+    file's served head is the embedding's transpose, which the program
+    holds as a copy."""
+    return Dense(vocab=cfg.vocab, d_model=cfg.d_model,
+                 n_layers=cfg.n_layers, n_heads=cfg.n_heads,
+                 n_kv_heads=cfg.n_kv_heads, d_ff=cfg.d_ff,
+                 qkv_bias=cfg.qkv_bias, rope_theta=cfg.rope_theta,
+                 rms_eps=PROGRAM_RMS_EPS,
+                 tied=bool(c["tie_word_embeddings"]))
+
+
+def to_program(ref: dict) -> dict:
+    """Reference layout (globals + stacked layers) -> the program's tree."""
+    lay = ref["layers"]
+    mix = {p: lay[r] for p, r in MIX.items() if r in lay}
+    ffn = {p: lay[r] for p, r in FFN.items()}
+    return {**{p: ref[r] for p, r in TOP.items()},
+            "layers": ({"mix": mix, "ffn": ffn},)}
+
+
+def to_reference(prog: dict) -> dict:
+    """The program's tree -> reference layout."""
+    blk = prog["layers"][0]
+    layers = {r: blk["mix"][p] for p, r in MIX.items() if p in blk["mix"]}
+    layers.update({r: blk["ffn"][p] for p, r in FFN.items()})
+    return {**{r: prog[p] for p, r in TOP.items()}, "layers": layers}
+
+
+# Operations and bytes each algorithm needs, from a configuration file's
+# shapes: the benchmark's own counts, never the program's
+# ``cost_analysis``, so work the program does beyond them (masked cache
+# positions, recomputation) does not count. A matrix product of (m, k) by
+# (k, n) is 2mkn operations; causal attention over a context of c
+# positions is 4 * heads * head_dim * c operations per query token (scores
+# and the weighted sum of values).
+
+BYTES = {"bfloat16": 2, "float32": 4}
+
+
+def layer_matmul_params(d: Dense) -> int:
+    q, kv = d.n_heads * d.head_dim, d.n_kv_heads * d.head_dim
+    return d.d_model * (q + 2 * kv) + q * d.d_model + 3 * d.d_model * d.d_ff
+
+
+def matmul_params(d: Dense) -> int:
+    """Parameters that take part in a product for every token: the layers'
+    projections and the output head (the embedding is a lookup)."""
+    return d.n_layers * layer_matmul_params(d) + d.d_model * d.vocab
+
+
+def weight_params(d: Dense) -> int:
+    """Parameters a decode step reads once: all but the embedding table (a
+    tied head is that table, read once as the head and counted once)."""
+    per_layer = layer_matmul_params(d) + 2 * d.d_model + (
+        (d.n_heads + 2 * d.n_kv_heads) * d.head_dim if d.qkv_bias else 0)
+    return d.n_layers * per_layer + d.d_model * d.vocab + d.d_model
+
+
+def attention_flops(d: Dense, context: int) -> int:
+    """Per query token and layer stack, over ``context`` positions."""
+    return d.n_layers * 4 * d.n_heads * d.head_dim * context
+
+
+def prefill_flops(c: dict, s: int) -> int:
+    """A prompt of ``s`` tokens: every layer over every token, causal
+    attention, and the output head at the last position only."""
+    d = Dense.from_config(c)
+    mm = 2 * s * d.n_layers * layer_matmul_params(d) + 2 * d.d_model * d.vocab
+    return mm + d.n_layers * 4 * d.n_heads * d.head_dim * s * (s + 1) // 2
+
+
+def decode_flops(c: dict, tokens: int, context: int) -> int:
+    """One decode step of ``tokens`` sequences whose contexts (the new token
+    included) add up to ``context`` positions."""
+    d = Dense.from_config(c)
+    return 2 * tokens * matmul_params(d) + attention_flops(d, context)
+
+
+def decode_bytes(c: dict, tokens: int, context: int) -> int:
+    """One decode step: every weight but the embedding once, one embedding
+    row per token, the keys and values of ``context`` positions read and of
+    ``tokens`` positions written, and the float32 logits written."""
+    d = Dense.from_config(c)
+    wb, ab = BYTES[c["param_dtype"]], BYTES[c["compute_dtype"]]
+    kv = d.n_layers * 2 * d.n_kv_heads * d.head_dim * ab
+    return (weight_params(d) * wb + tokens * d.d_model * wb
+            + kv * (context + tokens) + tokens * d.vocab * 4)
+
+
+def train_flops_per_token(c: dict, seq: int) -> int:
+    """Forward and backward (three times the forward) of one token of a
+    ``seq``-token row: 6 per product parameter, plus causal attention over
+    (seq + 1) / 2 positions on average. Recomputation does not count."""
+    d = Dense.from_config(c)
+    return 6 * matmul_params(d) + 3 * attention_flops(d, 1) * (seq + 1) // 2

@@ -6,7 +6,8 @@ machine.
 
 Everything is found by name from ``BENCHMARK.json``: the cell names a
 configuration (``bench/configs/<config>.json``, which names its driver,
-``bench/drivers/<path>.py``) and a traffic mix
+``bench/drivers/<path>.py``, and its model family's reference module,
+``bench/reference/<family>.py``: ``bench/program.py``) and a traffic mix
 (``bench/traffic/<mix>.json``); each per-layer metric is
 ``bench/metrics/<metric>.json``, which names a reader
 (``bench/readers/<reader>.py``) and that reader's arguments.

@@ -42,8 +42,7 @@ def main(argv=None) -> None:
     enable_compile_cache()
     cfg = program.arch_config(c["config"])
     geo = c["config"]["serve"]
-    params = program.make_params(cfg, args.seed,
-                                 c["config"]["tie_word_embeddings"])
+    params = program.make_params(c["config"], cfg, args.seed)
     srv = serve.Server(params, cfg,
                        n_slots=geo["slots"], max_len=geo["max_len"])
     every_length = traffic.quantile_lengths(c["mix"]["prompt"], 4096)

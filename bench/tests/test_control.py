@@ -7,7 +7,7 @@ comes out correct. At full size they are read on the chip by
 import numpy as np
 import pytest
 
-from bench import control
+from bench.drivers import serve, train
 from bench.tests.harness import correct, run_tiny
 
 SEEDS = (5, 6)
@@ -17,7 +17,7 @@ SEEDS = (5, 6)
 def test_serve_control_reads_beyond_the_program(seed):
     run = run_tiny("qwen2-1.5b.chat", seed=seed)
     lim = run.compared["limits"]
-    got = control.control_serve(run, seed, lim)
+    got = serve.control(run, seed, lim)
     mean = "logit_gap_mean"
     assert correct(run)
     # fp8 fails the committed limit already at this size; int8's rounding
@@ -31,9 +31,22 @@ def test_serve_control_reads_beyond_the_program(seed):
 @pytest.mark.parametrize("seed", SEEDS)
 def test_train_control_and_half_batch_fail_the_limits(seed):
     run = run_tiny("smollm-360m.train", seed=seed)
-    got = control.control_train(run, seed, run.compared["limits"])
+    got = train.control(run, seed, run.compared["limits"])
     assert correct(run)
     assert got["bf16"]["correct"] is False
     assert got["half_batch"]["correct"] is False
     assert np.isfinite([v for k, v in got["bf16"].items()
                         if k != "correct"]).all()
+
+
+def test_reference_in_blocks_reads_as_in_one(monkeypatch):
+    """The longprompt check runs its reference a block of sequences at a
+    time; the gaps do not depend on the block."""
+    run = run_tiny("qwen2-1.5b.longprompt", seed=SEEDS[0])
+    whole = run.compared["gaps"]
+    assert len(whole) > 3
+    monkeypatch.setattr(serve, "REF_BLOCK", 3)
+    blocks = serve._reference_gaps(run.compared, SEEDS[0])
+    assert [len(g) for g in blocks] == [len(g) for g in whole]
+    for a, b in zip(blocks, whole):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)

@@ -1,8 +1,9 @@
-"""The benchmark's operation and byte counts against counts worked by hand
+"""The dense family's operation and byte counts against counts worked by hand
 from the published shapes, and the table of peaks."""
 import pytest
 
-from bench import common, flops
+from bench import common
+from bench.reference import dense
 from bench.run import ROOT
 
 QWEN = common.load_json(ROOT, "bench", "configs", "qwen2-1.5b.json")
@@ -22,12 +23,12 @@ SMOL_LAYER, SMOL_MM = 9_830_400, 361_758_720
 
 
 def test_parameter_counts():
-    q, s = flops.spec(QWEN), flops.spec(SMOL)
-    assert flops.layer_matmul_params(q) == QWEN_LAYER
-    assert flops.matmul_params(q) == QWEN_MM
-    assert flops.weight_params(q) == QWEN_READ
-    assert flops.layer_matmul_params(s) == SMOL_LAYER
-    assert flops.matmul_params(s) == SMOL_MM
+    q, s = dense.Dense.from_config(QWEN), dense.Dense.from_config(SMOL)
+    assert dense.layer_matmul_params(q) == QWEN_LAYER
+    assert dense.matmul_params(q) == QWEN_MM
+    assert dense.weight_params(q) == QWEN_READ
+    assert dense.layer_matmul_params(s) == SMOL_LAYER
+    assert dense.matmul_params(s) == SMOL_MM
     # whole model in bf16 with the embedding: 3.554 GB, as the program's
     # compiled decode step reports
     assert (QWEN_READ + 151_936 * 1536) * 2 == 3_554_176_000
@@ -39,29 +40,29 @@ def test_decode_bytes():
     # position for 100 read + 1 written; f32 logits 151,936 x 4
     want = (2 * QWEN_READ + 3_072 + 28_672 * 101 + 607_744)
     assert want == 3_090_935_296
-    assert flops.decode_bytes(QWEN, 1, 100) == want
+    assert dense.decode_bytes(QWEN, 1, 100) == want
 
 
 def test_decode_flops():
     # 2 x 1,543,569,408 + 28 layers x 4 x 12 heads x 128 x 100 positions
-    assert flops.decode_flops(QWEN, 1, 100) == 3_087_138_816 + 17_203_200
+    assert dense.decode_flops(QWEN, 1, 100) == 3_087_138_816 + 17_203_200
 
 
 def test_prefill_flops():
     # 128 tokens: 2 x 128 x 28 x 46,792,704 = 335,410,102,272; head at the
     # last position 2 x 1536 x 151,936 = 466,747,392; causal attention
     # 28 x 4 x 12 x 128 = 172,032 per query-key pair x 128 x 129 / 2
-    assert flops.prefill_flops(QWEN, 128) == (
+    assert dense.prefill_flops(QWEN, 128) == (
         335_410_102_272 + 466_747_392 + 172_032 * 8_256)
 
 
 def test_train_flops_per_token():
     # 6 x parameters in products, plus 3 x attention over 2049 / 2
     # positions: smollm 3 x 32 x 4 x 15 x 64 = 368,640 per position
-    assert flops.train_flops_per_token(SMOL, 2048) == (
+    assert dense.train_flops_per_token(SMOL, 2048) == (
         6 * SMOL_MM + 368_640 * 2049 // 2) == 2_548_224_000
     # qwen2: 3 x 28 x 4 x 12 x 128 = 516,096 per position
-    assert flops.train_flops_per_token(QWEN, 2048) == (
+    assert dense.train_flops_per_token(QWEN, 2048) == (
         6 * QWEN_MM + 516_096 * 2049 // 2) == 9_790_156_800
 
 

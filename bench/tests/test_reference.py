@@ -24,10 +24,12 @@ SEED = 2 ** 31 + 77
 
 
 def _setup(arch, tied=False):
+    c = dict(common.load_json(ROOT, "bench", "configs", arch + ".json"),
+             tie_word_embeddings=tied)
     cfg = get_config(arch, reduced=True).with_(param_dtype=jnp.float32,
                                                dtype=jnp.float32)
-    spec = program.spec_of(cfg, tied)
-    params = program.make_params(cfg, SEED, tied)
+    spec = dense.spec_of(c, cfg)
+    params = program.make_params(c, cfg, SEED)
     return cfg, spec, params
 
 
@@ -108,10 +110,10 @@ def test_loss_and_grad_match_reference(arch):
     (loss, _), grads = jax.value_and_grad(T.loss_fn, has_aux=True)(
         params, batch, cfg)
     ref_loss, ref_grads = dense.loss_and_grad(
-        program.to_reference(params), jnp.asarray(tokens),
+        dense.to_reference(params), jnp.asarray(tokens),
         jnp.asarray(labels), spec, "f32")
     assert abs(float(loss) - float(ref_loss)) / float(ref_loss) < REL
-    got = dense.leaf_norms(program.to_reference(grads))
+    got = dense.leaf_norms(dense.to_reference(grads))
     want = dense.leaf_norms(ref_grads)
     for k in want:
         np.testing.assert_allclose(got[k], want[k], rtol=REL, err_msg=k)

@@ -13,25 +13,96 @@ with a ``bench.window`` host annotation.
   in it, as a loop holds its body), named ``<program>/<op>`` by the
   ``XLA Modules`` event around it and the op's HLO name, summed over the
   window.
+- Programs: for each program (the ``XLA Modules`` event's name with its
+  hash stripped: ``jit_serve_decode``), its executions that ran wholly
+  inside the window, their ops' self time per execution, and that time
+  split by the innermost of the family's named ``scopes`` in each op's
+  ``op_name``, or ``unscoped``. An op's ``op_name`` is its own (the
+  ``tf_op`` stat of its metadata); an op without one is joined to the
+  module's compiled HLO: an instruction that has an ``op_name`` takes its
+  scope from it, a fusion without one the scope most of its fused
+  instructions carry, any other instruction its caller's (the instruction
+  whose called computation holds it), and one with none of these is
+  ``unscoped``.
+
+Idle gaps, device ops and programs are read on the first device.
 """
 from __future__ import annotations
 
 import bisect
 import glob
 import os
-from collections import defaultdict
-from typing import Dict, List, Tuple
+import re
+from collections import Counter, defaultdict
+from typing import Dict, List, Optional, Sequence, Tuple
 
 TOP = 10
 WINDOW = "bench.window"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 
+UNSCOPED = "unscoped"
+
 Span = Tuple[float, float, str]       # start_ns, end_ns, name
+Op = Tuple[float, float, str, Optional[str]]    # ... and its op_name
 
 
 def _events(line) -> List[Span]:
     return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
             for e in line.events]
+
+
+def _ops(line) -> List[Op]:
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+             getattr(e, "op_name", None)) for e in line.events]
+
+
+def program_name(module: str) -> str:
+    """``jit_serve_decode(2703800818024598164)`` -> ``jit_serve_decode``."""
+    return re.sub(r"\(\d+\)$", "", module)
+
+
+def op_scope(op_name: str, scopes: Sequence[str]) -> str:
+    """The innermost of ``scopes`` that an ``op_name`` names, as a part of
+    its path or inside a transform (``transpose(jvp(mlp))``)."""
+    found = UNSCOPED
+    for part in re.split(r"[^A-Za-z0-9_]+", op_name):
+        if part in scopes:
+            found = part
+    return found
+
+
+def hlo_scopes(module, scopes: Sequence[str]) -> Dict[str, str]:
+    """The scope of every instruction of a compiled module (an
+    ``HloModuleProto``), by the join rule of the module docstring."""
+    comps = {c.id: c for c in module.computations}
+    own, caller, home = {}, {}, {}
+    for c in module.computations:
+        for i in c.instructions:
+            own[i.name] = (op_scope(i.metadata.op_name, scopes)
+                           if i.metadata.op_name else None)
+            home[i.name] = c.id
+            for cid in i.called_computation_ids:
+                caller.setdefault(cid, i)
+    out: Dict[str, str] = {}
+
+    def scope(i) -> str:
+        if i.name not in out:
+            s = own[i.name]
+            if s is None and i.opcode == "fusion":
+                named = [own[f.name] for cid in i.called_computation_ids
+                         for f in comps[cid].instructions
+                         if own[f.name] not in (None, UNSCOPED)]
+                s = Counter(named).most_common(1)[0][0] if named else None
+            if s is None:
+                up = caller.get(home[i.name])
+                s = scope(up) if up is not None else UNSCOPED
+            out[i.name] = s
+        return out[i.name]
+
+    for c in module.computations:
+        for i in c.instructions:
+            scope(i)
+    return out
 
 
 def _union(spans: List[Span], lo: float, hi: float) -> List[Tuple[float,
@@ -66,12 +137,15 @@ def _label(s: float, e: float, marks: List[Span], starts: List[float]
     return name
 
 
-def reduce_planes(planes) -> Dict:
+def reduce_planes(planes, scopes: Sequence[str] = (),
+                  hlo: Optional[Dict[str, object]] = None) -> Dict:
+    """The reduction of the module docstring; ``hlo`` holds each module's
+    compiled HLO by its name, for the ops that carry no ``op_name``."""
     host, devices = [], []
     for p in planes:
         lines = {ln.name: ln for ln in p.lines}
         if p.name.startswith("/device:") and OPS_LINE in lines:
-            devices.append((_events(lines[OPS_LINE]),
+            devices.append((_ops(lines[OPS_LINE]),
                             _events(lines[MODULES_LINE])
                             if MODULES_LINE in lines else []))
         elif p.name.startswith("/host:"):
@@ -84,7 +158,7 @@ def reduce_planes(planes) -> Dict:
     marks = sorted(s for s in host
                    if s[2] != WINDOW and s[1] > lo and s[0] < hi)
     mark_starts = [m[0] for m in marks]
-    busies = [_union(ops, lo, hi) for ops, _ in devices]
+    busies = [_union([op[:3] for op in ops], lo, hi) for ops, _ in devices]
     busies = [b for b in busies if b]
     if not busies:
         raise ValueError("no device operation ran inside the window")
@@ -94,7 +168,8 @@ def reduce_planes(planes) -> Dict:
     ops, mods = devices[0]
     mods = sorted(mods)
     starts = [m[0] for m in mods]
-    for s, self_ns, name in _self_times(ops, lo, hi):
+    selfs = _self_times(ops, lo, hi)
+    for s, self_ns, name, _ in selfs:
         j = bisect.bisect_right(starts, s) - 1
         prog = mods[j][2] if j >= 0 and mods[j][1] >= s else "?"
         ops_ns[f"{prog}/{name.split(' = ')[0].lstrip('%')}"] += self_ns
@@ -107,22 +182,55 @@ def reduce_planes(planes) -> Dict:
             ops_ns.items(), key=lambda kv: -kv[1])[:TOP]],
         "idle_gaps": [[name, ns / 1e9] for ns, name in gaps[:TOP]],
         "idle_by_host_span": {k: v / 1e9 for k, v in _sum_by(gaps).items()},
+        "programs": _programs(selfs, mods, lo, hi, scopes, hlo or {}),
     }
 
 
-def _self_times(ops: List[Span], lo: float, hi: float):
-    """(start, self time, name) of each op clipped to [lo, hi]."""
-    clipped = sorted(((max(s, lo), min(e, hi), n) for s, e, n in ops
+def _programs(selfs, mods: List[Span], lo: float, hi: float,
+              scopes: Sequence[str], hlo: Dict[str, object]) -> Dict:
+    """Per program: ``executions`` that ran wholly inside [lo, hi], the
+    self time of their ops (``selfs``, from :func:`_self_times`) per
+    execution (``device_ms``), and that time by scope (``scope_ms``, the
+    scopes whose ops took any)."""
+    runs = sorted(m for m in mods if lo <= m[0] and m[1] <= hi)
+    starts = [m[0] for m in runs]
+    count: Dict[str, int] = Counter(program_name(m[2]) for m in runs)
+    ns: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
+    joined: Dict[str, Dict[str, str]] = {}
+    for s, self_ns, name, op_name in selfs:
+        j = bisect.bisect_right(starts, s) - 1
+        if j < 0 or runs[j][1] < s:
+            continue
+        module = runs[j][2]
+        if op_name:
+            scope = op_scope(op_name, scopes)
+        else:
+            if module not in joined:
+                joined[module] = (hlo_scopes(hlo[module], scopes)
+                                  if module in hlo else {})
+            scope = joined[module].get(name.split(" = ")[0].lstrip("%"),
+                                       UNSCOPED)
+        ns[program_name(module)][scope] += self_ns
+    return {prog: {"executions": n,
+                   "device_ms": sum(ns[prog].values()) / n / 1e6,
+                   "scope_ms": {k: v / n / 1e6
+                                for k, v in sorted(ns[prog].items()) if v}}
+            for prog, n in sorted(count.items())}
+
+
+def _self_times(ops: List[Op], lo: float, hi: float):
+    """(start, self time, name, op_name) of each op clipped to [lo, hi]."""
+    clipped = sorted(((max(s, lo), min(e, hi), n, o) for s, e, n, o in ops
                       if min(e, hi) > max(s, lo)), key=lambda x: (x[0], -x[1]))
-    out, stack = [], []          # stack entries: [start, end, name, inner]
-    for s, e, n in clipped:
+    out, stack = [], []       # stack entries: [start, end, name, op, inner]
+    for s, e, n, o in clipped:
         while stack and stack[-1][1] <= s:
             t = stack.pop()
-            out.append((t[0], t[1] - t[0] - t[3], t[2]))
+            out.append((t[0], t[1] - t[0] - t[4], t[2], t[3]))
         if stack:
-            stack[-1][3] += e - s
-        stack.append([s, e, n, 0.0])
-    out += [(t[0], t[1] - t[0] - t[3], t[2]) for t in stack]
+            stack[-1][4] += e - s
+        stack.append([s, e, n, o, 0.0])
+    out += [(t[0], t[1] - t[0] - t[4], t[2], t[3]) for t in stack]
     return out
 
 
@@ -133,15 +241,16 @@ def _sum_by(gaps) -> Dict[str, float]:
     return dict(out)
 
 
-def reduce_file(path: str) -> Dict:
-    from jax.profiler import ProfileData
-    return reduce_planes(ProfileData.from_file(path).planes)
+def reduce_file(path: str, scopes: Sequence[str] = ()) -> Dict:
+    from bench import xplane
+    planes, hlo = xplane.read(path)
+    return reduce_planes(planes, scopes, hlo)
 
 
-def reduce_dir(out_dir: str) -> Dict:
+def reduce_dir(out_dir: str, scopes: Sequence[str] = ()) -> Dict:
     found = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if len(found) != 1:
         raise ValueError(f"expected one .xplane.pb under {out_dir}, found "
                          f"{found}")
-    return reduce_file(found[0])
+    return reduce_file(found[0], scopes)
