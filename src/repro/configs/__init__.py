@@ -14,7 +14,7 @@ from repro.configs import (  # noqa: E402
     jamba_1_5_large_398b,
     minitron_4b,
     mixtral_8x22b,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     qwen1_5_4b,
     qwen2_1_5b,
     smollm_360m,
@@ -23,7 +23,7 @@ from repro.configs import (  # noqa: E402
 )
 
 _MODULES = {
-    "moonshot-v1-16b-a3b": moonshot_v1_16b_a3b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
     "mixtral-8x22b": mixtral_8x22b,
     "xlstm-1.3b": xlstm_1_3b,
     "jamba-1.5-large-398b": jamba_1_5_large_398b,

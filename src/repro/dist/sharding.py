@@ -53,7 +53,7 @@ DATA_AXIS_ORDER: Tuple[str, ...] = ("pod", "data")
 # interacts badly with their chunked scan (the per-chunk carry would cross
 # shard boundaries every step), so the recommended rules disable SP.
 _RECURRENT_MIXERS = frozenset({"mamba", "mlstm", "slstm"})
-_ATTENTION_MIXERS = frozenset({"attn", "swa"})
+_ATTENTION_MIXERS = frozenset({"attn", "swa", "mla"})
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +172,7 @@ def _path_names(path) -> Tuple[str, ...]:
 # matching activations stay replicated on entry, sharded on exit.
 _COLUMN = frozenset({
     "wq", "wk", "wv",            # attention qkv
+    "wkv_b",                     # MLA per-head key/value expansion
     "w_gate", "w_up", "w_in",    # swiglu / gelu MLP up-projections
     "in_proj", "dt_proj",        # mamba expand + dt
     "wz", "wi", "wf",            # xLSTM input/gate projections
@@ -202,7 +203,7 @@ def _param_spec(names: Tuple[str, ...], shape: Tuple[int, ...],
     spec: list = [None] * ndim
     # Layer stacks carry a leading repeats dim (lax.scan axis) — never
     # sharded: every device owns every layer's slice of each weight.
-    stacked = bool(names) and names[0] in ("layers", "enc_layers")
+    stacked = bool(names) and names[0] in ("layers", "enc_layers", "lead")
     off = 1 if stacked else 0
     name = names[-1] if names else ""
 
@@ -228,7 +229,8 @@ def _param_spec(names: Tuple[str, ...], shape: Tuple[int, ...],
         spec[ndim - 1] = fit_axes(shape[-1], tp, mesh)
     elif name in _CHANNEL_FIRST and ndim - off == 2:
         spec[off] = fit_axes(shape[off], tp, mesh)
-    # everything else (norms, routers, recurrent r-mats): replicated
+    # everything else (norms, routers and their bias, MLA's latent
+    # projection, recurrent r-mats): replicated
 
     if rules.fsdp_weights and int(np.prod(shape)) >= rules.fsdp_min_size:
         dp = data_axes(mesh, tp)

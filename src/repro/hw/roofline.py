@@ -47,8 +47,7 @@ def _param_counts(cfg: ArchConfig) -> Dict[str, float]:
 
 
 def _attn_layers(cfg: ArchConfig) -> int:
-    per_period = sum(1 for m, _ in cfg.pattern if m in ("attn", "swa"))
-    return per_period * cfg.repeats
+    return sum(1 for m, _ in cfg.layer_kinds if m in ("attn", "swa", "mla"))
 
 
 def model_flops(cfg: ArchConfig, kind: str, seq: int, batch: int,
@@ -82,20 +81,22 @@ def kv_cache_bytes(cfg: ArchConfig, seq: int, batch: int) -> float:
     """Global decode-state bytes (KV caches + recurrent states)."""
     dt = 2.0  # bf16
     total = 0.0
-    for mixer, _ in cfg.pattern:
-        n = cfg.repeats
-        if mixer in ("attn", "swa"):
+    for mixer, _ in cfg.layer_kinds:     # one term per layer
+        if mixer == "mla":
+            total += batch * seq * (cfg.kv_lora_rank
+                                    + cfg.qk_rope_head_dim) * dt
+        elif mixer in ("attn", "swa"):
             s = min(seq, cfg.swa_window) if (mixer == "swa"
                                              and cfg.swa_window) else seq
-            total += n * 2 * batch * s * cfg.n_kv_heads * cfg.head_dim * dt
+            total += 2 * batch * s * cfg.n_kv_heads * cfg.head_dim * dt
         elif mixer == "mamba":
             di = 2 * cfg.d_model
-            total += n * batch * di * (cfg.d_state + 3) * 4.0
+            total += batch * di * (cfg.d_state + 3) * 4.0
         elif mixer in ("mlstm",):
             dh = cfg.head_dim
-            total += n * batch * cfg.n_heads * (dh * dh + dh + 1) * 4.0
+            total += batch * cfg.n_heads * (dh * dh + dh + 1) * 4.0
         elif mixer == "slstm":
-            total += n * batch * 4 * cfg.d_model * 4.0
+            total += batch * 4 * cfg.d_model * 4.0
     return total
 
 

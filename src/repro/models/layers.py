@@ -102,8 +102,9 @@ def init_mlp(rng, d_model: int, d_ff: int, variant: str, dtype) -> Params:
             "b_out": jnp.zeros((d_model,), dtype)}
 
 
-def mlp(x: jnp.ndarray, p: Params, variant: str = "swiglu") -> jnp.ndarray:
-    h = rmsnorm(x, p["ln"])
+def mlp(x: jnp.ndarray, p: Params, variant: str = "swiglu",
+        eps: float = 1e-6) -> jnp.ndarray:
+    h = rmsnorm(x, p["ln"], eps)
     if variant == "swiglu":
         g = jax.nn.silu(dense(h, p["w_gate"]))
         u = dense(h, p["w_up"])
@@ -131,9 +132,9 @@ def _mask_for(ci, chunk, rows, sk, causal, window):
 
 
 def _chunked_attn_fwd_impl(q, k, v, causal, window, chunk, q_offset):
-    """Returns (out (B,Sq,HQ,D), lse (B,KV,G,Sq))."""
+    """Returns (out (B,Sq,HQ,Dv), lse (B,KV,G,Sq))."""
     b, sq, hq, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv_w = k.shape[1], k.shape[2], v.shape[-1]
     group = hq // hkv
     chunk = min(chunk, sk)
     n_chunks = -(-sk // chunk)
@@ -144,7 +145,7 @@ def _chunked_attn_fwd_impl(q, k, v, causal, window, chunk, q_offset):
     scale = 1.0 / math.sqrt(d)
     qg = (q.astype(jnp.float32) * scale).reshape(b, sq, hkv, group, d)
     kc = k.reshape(b, n_chunks, chunk, hkv, d)
-    vc = v.reshape(b, n_chunks, chunk, hkv, d)
+    vc = v.reshape(b, n_chunks, chunk, hkv, dv_w)
     rows = q_offset + jnp.arange(sq)
 
     def step(carry, inp):
@@ -167,7 +168,7 @@ def _chunked_attn_fwd_impl(q, k, v, causal, window, chunk, q_offset):
 
     m0 = jnp.full((b, hkv, group, sq), -1e30, jnp.float32)
     l0 = jnp.zeros((b, hkv, group, sq), jnp.float32)
-    acc0 = jnp.zeros((b, hkv, group, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, hkv, group, sq, dv_w), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, acc0),
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0),
@@ -175,7 +176,7 @@ def _chunked_attn_fwd_impl(q, k, v, causal, window, chunk, q_offset):
     lsafe = jnp.where(l == 0, 1.0, l)
     out = acc / lsafe[..., None]
     lse = m + jnp.log(lsafe)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, hq, d).astype(q.dtype)
+    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, hq, dv_w).astype(q.dtype)
     return out, lse
 
 
@@ -184,7 +185,8 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       causal: bool = True, window: Optional[int] = None,
                       chunk: int = 1024,
                       q_offset: int = 0) -> jnp.ndarray:
-    """Online-softmax attention, KV-chunked. q:(B,Sq,H,D) k,v:(B,Sk,KV,D).
+    """Online-softmax attention, KV-chunked. q, k: (B,Sq|Sk,H|KV,D), v:
+    (B,Sk,KV,Dv); the output is (B,Sq,H,Dv), scaled by 1/sqrt(D).
 
     Memory O(Sq * chunk) in both passes. ``q_offset``: absolute position of
     q[0] (prefill continuation)."""
@@ -201,7 +203,7 @@ def _chunked_attn_fwd(q, k, v, causal, window, chunk, q_offset):
 def _chunked_attn_bwd(causal, window, chunk, q_offset, res, dout):
     q, k, v, out, lse = res
     b, sq, hq, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
+    sk, hkv, dv_w = k.shape[1], k.shape[2], v.shape[-1]
     group = hq // hkv
     chunk = min(chunk, sk)
     n_chunks = -(-sk // chunk)
@@ -211,13 +213,13 @@ def _chunked_attn_bwd(causal, window, chunk, q_offset, res, dout):
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     scale = 1.0 / math.sqrt(d)
     qg = (q.astype(jnp.float32) * scale).reshape(b, sq, hkv, group, d)
-    dog = dout.astype(jnp.float32).reshape(b, sq, hkv, group, d)
-    og = out.astype(jnp.float32).reshape(b, sq, hkv, group, d)
+    dog = dout.astype(jnp.float32).reshape(b, sq, hkv, group, dv_w)
+    og = out.astype(jnp.float32).reshape(b, sq, hkv, group, dv_w)
     dog = jnp.moveaxis(dog, 1, 3)   # (B,KV,G,Sq,D)
     og = jnp.moveaxis(og, 1, 3)
     delta = jnp.sum(dog * og, axis=-1)            # (B,KV,G,Sq)
     kc = jnp.moveaxis(k.reshape(b, n_chunks, chunk, hkv, d), 1, 0)
-    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hkv, d), 1, 0)
+    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hkv, dv_w), 1, 0)
     rows = q_offset + jnp.arange(sq)
 
     def step(dq, inp):
@@ -240,7 +242,7 @@ def _chunked_attn_bwd(causal, window, chunk, q_offset, res, dout):
     dq, (dk_c, dv_c) = jax.lax.scan(
         step, dq0, (kc, vc, jnp.arange(n_chunks)))
     dk = jnp.moveaxis(dk_c, 0, 1).reshape(b, n_chunks * chunk, hkv, d)
-    dv = jnp.moveaxis(dv_c, 0, 1).reshape(b, n_chunks * chunk, hkv, d)
+    dv = jnp.moveaxis(dv_c, 0, 1).reshape(b, n_chunks * chunk, hkv, dv_w)
     dq = dq.reshape(b, sq, hq, d).astype(q.dtype)
     return dq, dk[:, :sk].astype(k.dtype), dv[:, :sk].astype(v.dtype)
 
@@ -333,3 +335,83 @@ def decode_attention_ring(q, k_cache, v_cache, position,
     smax = k_cache.shape[1]
     filled = jnp.minimum(jnp.asarray(position) + 1, smax)
     return decode_attention(q, k_cache, v_cache, filled, window=None)
+
+
+# ------------------------------------------------ multi-head latent attention
+#
+# DeepSeek-V2/V3 MLA without a query latent: per head a query of
+# ``nope + rope`` dims; one ``kv_lora_rank``-wide latent ``c`` per position
+# (RMS-normed) from which each head's key-nope and value are expanded, and
+# one ``rope``-wide key shared by all heads. The decode cache holds only
+# ``c`` and the roped shared key.
+
+def init_mla(rng, d_model: int, n_heads: int, kv_lora: int, nope: int,
+             rope_dim: int, v_dim: int, dtype) -> Params:
+    rs = jax.random.split(rng, 4)
+    return {
+        "ln": jnp.ones((d_model,), dtype),
+        "wq": _winit(rs[0], (d_model, n_heads * (nope + rope_dim)), d_model,
+                     dtype),
+        "wkv_a": _winit(rs[1], (d_model, kv_lora + rope_dim), d_model,
+                        dtype),
+        "kv_ln": jnp.ones((kv_lora,), dtype),
+        "wkv_b": _winit(rs[2], (kv_lora, n_heads * (nope + v_dim)), kv_lora,
+                        dtype),
+        "wo": _winit(rs[3], (n_heads * v_dim, d_model), n_heads * v_dim,
+                     dtype),
+    }
+
+
+def mla_project(h: jnp.ndarray, p: Params, cfg, positions: jnp.ndarray):
+    """The normed input h (B, S, D) -> roped queries (B, S, H, nope+rope),
+    the normed latent c (B, S, kv_lora) and the roped shared key
+    (B, S, rope)."""
+    b, s, _ = h.shape
+    nope, r, lora = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.kv_lora_rank
+    q = dense(h, p["wq"]).reshape(b, s, cfg.n_heads, nope + r)
+    q = jnp.concatenate(
+        [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+    kva = dense(h, p["wkv_a"])
+    c = rmsnorm(kva[..., :lora], p["kv_ln"], cfg.rms_eps)
+    k_pe = rope(kva[..., None, lora:], positions, cfg.rope_theta)[..., 0, :]
+    return q, c, k_pe
+
+
+def mla_expand(c: jnp.ndarray, k_pe: jnp.ndarray, p: Params, cfg):
+    """Per-head keys (B, S, H, nope+rope) and values (B, S, H, v) from the
+    latent and the shared roped key (prefill and training)."""
+    b, s, _ = c.shape
+    nope, h = cfg.qk_nope_head_dim, cfg.n_heads
+    kv = dense(c, p["wkv_b"]).reshape(b, s, h, nope + cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, k_pe.shape[-1]))
+    return jnp.concatenate([kv[..., :nope], k_pe], -1), kv[..., nope:]
+
+
+def mla_decode_attention(q: jnp.ndarray, c_cache: jnp.ndarray,
+                         kpe_cache: jnp.ndarray, wkv_b: jnp.ndarray,
+                         cache_len: jnp.ndarray, cfg) -> jnp.ndarray:
+    """One-token MLA against the latent cache, with the key and value
+    expansions absorbed: each head's nope query goes through its key
+    expansion into the latent's width, the scores read the latent and the
+    shared key once for all heads, and the weighted latent goes through the
+    value expansion. q: (B, 1, H, nope+rope); caches (B, S, kv_lora) and
+    (B, S, rope); returns (B, 1, H, v)."""
+    b, _, h, dq = q.shape
+    nope, lora = cfg.qk_nope_head_dim, c_cache.shape[-1]
+    w = wkv_b.astype(q.dtype).reshape(lora, h, nope + cfg.v_head_dim)
+    q_lat = jnp.einsum("bhn,chn->bhc", q[:, 0, :, :nope], w[..., :nope],
+                       preferred_element_type=jnp.float32).astype(q.dtype)
+    s = (jnp.einsum("bhc,bkc->bhk", q_lat, c_cache,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,bkr->bhk", q[:, 0, :, nope:], kpe_cache,
+                      preferred_element_type=jnp.float32)) / math.sqrt(dq)
+    length = jnp.broadcast_to(jnp.asarray(cache_len), (b,))
+    mask = jnp.arange(c_cache.shape[1])[None, :] < length[:, None]
+    s = jnp.where(mask[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhk,bkc->bhc", p.astype(c_cache.dtype), c_cache,
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.einsum("bhc,chv->bhv", o, w[..., nope:],
+                     preferred_element_type=jnp.float32)
+    return out[:, None].astype(q.dtype)

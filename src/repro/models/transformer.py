@@ -6,8 +6,12 @@ The layer stack is ``lax.scan`` over period repeats with weights stacked on a
 leading repeat axis, so HLO size is O(period), not O(n_layers) — essential
 for compiling 72-layer models against a 512-device mesh.
 
-Mixers:  attn | swa | mamba | mlstm | slstm | none
+Mixers:  attn | swa | mla | mamba | mlstm | slstm | none
 FFNs:    mlp  | moe | gelu  | none
+
+``n_dense_lead`` layers of (the pattern's first mixer, ``mlp``) may run
+ahead of the scanned pattern, as their own stack (``params["lead"]``,
+``cache["lead"]``), e.g. DeepSeek-V3's leading dense layers.
 
 Three entry points (built by ``repro.train.steps``):
   train:   tokens -> chunked-softmax xent loss (never materializes B,S,V)
@@ -87,13 +91,29 @@ class ArchConfig:
     use_rope: bool = True
     rope_theta: float = 10000.0
     attn_chunk: int = 1024
+    rms_eps: float = 1e-6
+    # multi-head latent attention (mixer "mla"): per head a query and key
+    # of qk_nope + qk_rope dims and a value of v_head_dim, expanded from a
+    # kv_lora_rank-wide latent (the cache holds it and the roped key)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # layers of (pattern[0] mixer, "mlp" of d_ff) ahead of the pattern
+    n_dense_lead: int = 0
     # moe
-    n_experts: int = 0
+    n_experts: int = 0          # the router's width: every expert
     moe_top_k: int = 0
     moe_capacity_factor: float = 1.25
     moe_group_size: int = 2048
     moe_impl: str = "dropping"
     aux_loss_weight: float = 0.01
+    moe_d_ff: int = 0           # expert width (0: d_ff)
+    # "dropless" only (sigmoid + selection-bias routing, repro.models.moe):
+    n_shared_experts: int = 0   # shared experts, one SwiGLU of n x moe width
+    routed_scale: float = 1.0   # the normalised top-k weights times this
+    n_held_experts: int = 0     # experts this chip holds (0: all)
+    first_expert: int = 0       # global id of the first held expert
     # ssm
     ssm_chunk: int = 64
     d_state: int = 16
@@ -108,6 +128,16 @@ class ArchConfig:
     param_dtype: Any = jnp.bfloat16
     remat: bool = True
     loss_chunk: int = 512
+
+    def __post_init__(self):
+        if self.moe_impl != "dropless" and (
+                self.n_shared_experts or self.routed_scale != 1.0
+                or self.n_held_experts or self.first_expert):
+            raise ValueError(
+                f"{self.name}: shared experts, routed_scale and a held "
+                f"share of the experts need moe_impl='dropless', not "
+                f"{self.moe_impl!r}")
+
     # long-context support marker (sub-quadratic mixers or SWA)
     @property
     def head_dim(self) -> int:
@@ -119,8 +149,34 @@ class ArchConfig:
 
     @property
     def repeats(self) -> int:
-        assert self.n_layers % self.period == 0, (self.n_layers, self.period)
-        return self.n_layers // self.period
+        body = self.n_layers - self.n_dense_lead
+        assert body % self.period == 0, (self.n_layers, self.period)
+        return body // self.period
+
+    @property
+    def lead_pattern(self) -> Tuple[Tuple[str, str], ...]:
+        return ((self.pattern[0][0], "mlp"),)
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, ffn) of every layer, in order."""
+        return (self.lead_pattern * self.n_dense_lead
+                + tuple(self.pattern) * self.repeats)
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_held_experts or self.n_experts
+
+    @property
+    def counts_moe(self) -> bool:
+        """Whether the MoE layers count (:mod:`repro.models.moe`
+        ``COUNTERS``); prefill and decode then hand the counters back."""
+        return self.moe_impl == "dropless" and any(
+            f == "moe" for _, f in self.pattern)
 
     @property
     def sub_quadratic(self) -> bool:
@@ -144,6 +200,10 @@ def _init_one_layer(rng, cfg: ArchConfig, mixer: str, ffn: str,
         p["mix"] = L.init_attention(rs[0], cfg.d_model, cfg.n_heads,
                                     cfg.n_kv_heads, cfg.head_dim,
                                     cfg.qkv_bias, dt)
+    elif mixer == "mla":
+        p["mix"] = L.init_mla(rs[0], cfg.d_model, cfg.n_heads,
+                              cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                              cfg.qk_rope_head_dim, cfg.v_head_dim, dt)
     elif mixer == "mamba":
         p["mix"] = SSM.init_mamba(rs[0], cfg.d_model, cfg.d_state, dtype=dt)
     elif mixer == "mlstm":
@@ -154,21 +214,23 @@ def _init_one_layer(rng, cfg: ArchConfig, mixer: str, ffn: str,
         p["cross"] = L.init_attention(rs[2], cfg.d_model, cfg.n_heads,
                                       cfg.n_kv_heads, cfg.head_dim, False, dt)
     if ffn == "moe":
-        p["ffn"] = MOE.init_moe(rs[1], cfg.d_model, cfg.d_ff, cfg.n_experts,
-                                dt)
+        p["ffn"] = MOE.init_moe(rs[1], cfg.d_model, cfg.expert_d_ff,
+                                cfg.held_experts, dt, n_router=cfg.n_experts,
+                                n_shared=cfg.n_shared_experts,
+                                router_bias=cfg.moe_impl == "dropless")
     elif ffn in ("mlp", "gelu"):
         variant = "swiglu" if ffn == "mlp" else "gelu"
         p["ffn"] = L.init_mlp(rs[1], cfg.d_model, cfg.d_ff, variant, dt)
     return p
 
 
-def _init_stack(rng, cfg: ArchConfig, n_layers: int, cross: bool
-                ) -> Tuple[Params, ...]:
+def _init_stack(rng, cfg: ArchConfig, n_layers: int, cross: bool,
+                pattern=None) -> Tuple[Params, ...]:
     """Stacked params per period position: tuple_p of pytrees (R, ...)."""
-    period = cfg.period
-    repeats = n_layers // period
+    pattern = pattern or cfg.pattern
+    repeats = n_layers // len(pattern)
     out = []
-    for pidx, (mixer, ffn) in enumerate(cfg.pattern):
+    for pidx, (mixer, ffn) in enumerate(pattern):
         keys = jax.random.split(jax.random.fold_in(rng, pidx), repeats)
         out.append(jax.vmap(
             lambda k: _init_one_layer(k, cfg, mixer, ffn, cross))(keys))
@@ -185,8 +247,12 @@ def init_params(rng, cfg: ArchConfig) -> Params:
         "final_ln": jnp.ones((cfg.d_model,), dt),
         "lm_head": (jax.random.normal(rs[1], (cfg.d_model, cfg.vocab),
                                       jnp.float32) * scale).astype(dt),
-        "layers": _init_stack(rs[2], cfg, cfg.n_layers, cross=cfg.enc_dec),
+        "layers": _init_stack(rs[2], cfg, cfg.n_layers - cfg.n_dense_lead,
+                              cross=cfg.enc_dec),
     }
+    if cfg.n_dense_lead:
+        params["lead"] = _init_stack(rs[4], cfg, cfg.n_dense_lead,
+                                     cross=False, pattern=cfg.lead_pattern)
     if cfg.enc_dec:
         enc_cfg = cfg.with_(pattern=(("attn", "gelu"),))
         params["enc_layers"] = _init_stack(rs[3], enc_cfg, cfg.n_enc_layers,
@@ -209,14 +275,16 @@ def abstract_params(rng, cfg: ArchConfig) -> Params:
 def _apply_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
                  positions, causal: bool,
                  enc_kv=None):
-    """Training/prefill block. Returns (x, aux, cache_entry)."""
+    """Training/prefill block. Returns (x, aux, cache_entry, counters or
+    None)."""
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     cache: Dict[str, Any] = {}
     if mixer in ("attn", "swa"):
         with jax.named_scope("attention"):
             window = cfg.swa_window if mixer == "swa" else None
             b, s, _ = x.shape
-            h = L.rmsnorm(x, p["mix"]["ln"])
+            h = L.rmsnorm(x, p["mix"]["ln"], cfg.rms_eps)
             q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
                 .reshape(b, s, cfg.n_heads, cfg.head_dim)
             k = L.dense(h, p["mix"]["wk"], p["mix"].get("bk")) \
@@ -230,6 +298,16 @@ def _apply_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
                                       chunk=cfg.attn_chunk)
             x = x + L.dense(out.reshape(b, s, -1), p["mix"]["wo"])
             cache["k"], cache["v"] = k, v
+    elif mixer == "mla":
+        with jax.named_scope("attention"):
+            b, s, _ = x.shape
+            h = L.rmsnorm(x, p["mix"]["ln"], cfg.rms_eps)
+            q, c, k_pe = L.mla_project(h, p["mix"], cfg, positions)
+            k, v = L.mla_expand(c, k_pe, p["mix"], cfg)
+            out = L.chunked_attention(q, k, v, causal=causal,
+                                      chunk=cfg.attn_chunk)
+            x = x + L.dense(out.reshape(b, s, -1), p["mix"]["wo"])
+            cache["c"], cache["kr"] = c, k_pe
     elif mixer == "mamba":
         x, st = SSM.mamba_block(x, p["mix"], cfg)
         cache["ssm"] = st
@@ -244,23 +322,33 @@ def _apply_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
         x = L.attention_block(x, p["cross"], cfg, positions, causal=False,
                               cross_kv=enc_kv)
 
-    with jax.named_scope("mlp"):
-        if ffn == "moe":
-            x, aux = MOE.moe_block(x, p["ffn"], cfg)
-        elif ffn in ("mlp", "gelu"):
-            x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu")
-    return x, aux, cache
+    if ffn == "moe":
+        with jax.named_scope("moe"):
+            x, aux, counts = MOE.moe_block(x, p["ffn"], cfg)
+    elif ffn in ("mlp", "gelu"):
+        with jax.named_scope("mlp"):
+            x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu",
+                      cfg.rms_eps)
+    return x, aux, cache, counts
+
+
+def _counts0(cfg: ArchConfig, pattern):
+    """The counters' initial sum, or None where no layer counts."""
+    if cfg.counts_moe and any(f == "moe" for _, f in pattern):
+        return jnp.zeros((len(MOE.COUNTERS),), jnp.int32)
+    return None
 
 
 def _run_stack(x, stack, cfg: ArchConfig, pattern, positions, causal,
                enc_out=None, collect_cache: bool = False):
-    """Scan over period repeats. Returns (x, aux_total, caches per pos)."""
+    """Scan over period repeats. Returns (x, aux_total, caches per pos,
+    counters summed over the layers or None)."""
 
     def one_block(x, p, positions, enc_kv, mixer, ffn):
         x = constrain_batch(_grad_cast(x))
-        x, aux_i, cache = _apply_block(x, p, cfg, mixer, ffn, positions,
-                                       causal, enc_kv)
-        return constrain_batch(x), aux_i, cache
+        x, aux_i, cache, n_i = _apply_block(x, p, cfg, mixer, ffn,
+                                            positions, causal, enc_kv)
+        return constrain_batch(x), aux_i, cache, n_i
 
     if cfg.remat:
         # nested remat: backward re-materializes one block at a time, so the
@@ -275,7 +363,7 @@ def _run_stack(x, stack, cfg: ArchConfig, pattern, positions, causal,
                      for mixer, ffn in set(pattern)}
 
     def period_body(carry, layer_params):
-        x, aux = carry
+        x, aux, n = carry
         caches = []
         for pidx, (mixer, ffn) in enumerate(pattern):
             p = layer_params[pidx]
@@ -288,21 +376,23 @@ def _run_stack(x, stack, cfg: ArchConfig, pattern, positions, causal,
                     .reshape(b, f, cfg.n_kv_heads, cfg.head_dim)
                 enc_kv = (k_enc, v_enc)
                 caches_entry_extra = {"xk": k_enc, "xv": v_enc}
-            x, aux_i, cache = block_fns[(mixer, ffn)](x, p, positions,
-                                                      enc_kv)
+            x, aux_i, cache, n_i = block_fns[(mixer, ffn)](
+                x, p, positions, enc_kv)
             if enc_out is not None and "cross" in p:
                 cache.update(caches_entry_extra)
             aux = aux + aux_i
+            if n_i is not None:
+                n = n + n_i
             caches.append(cache)
-        return (x, aux), tuple(caches) if collect_cache else None
+        return (x, aux, n), tuple(caches) if collect_cache else None
 
     # outer remat: the scan saves only the period-boundary carry; inner
     # per-block remat (above) keeps the period backward to one block's
     # internals at a time.
     body = jax.checkpoint(period_body) if cfg.remat else period_body
-    (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                    stack)
-    return x, aux, caches
+    (x, aux, n), caches = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), _counts0(cfg, pattern)), stack)
+    return x, aux, caches, n
 
 
 # ------------------------------------------------------------------ forward
@@ -330,21 +420,35 @@ def encode(params: Params, batch: Dict[str, jnp.ndarray],
     x = frames + L.sinusoidal_positions(f, cfg.d_model, cfg.dtype)
     pos = jnp.broadcast_to(jnp.arange(f), (b, f))
     enc_cfg = cfg.with_(pattern=(("attn", "gelu"),), use_rope=False)
-    x, _, _ = _run_stack(x, params["enc_layers"], enc_cfg,
-                         enc_cfg.pattern, pos, causal=False)
-    return L.rmsnorm(x, params["enc_ln"])
+    x, _, _, _ = _run_stack(x, params["enc_layers"], enc_cfg,
+                            enc_cfg.pattern, pos, causal=False)
+    return L.rmsnorm(x, params["enc_ln"], cfg.rms_eps)
+
+
+def _forward(params: Params, batch: Dict[str, jnp.ndarray],
+             cfg: ArchConfig, collect_cache: bool = False):
+    """Full forward to final hidden states. Returns (h, aux, caches of
+    the pattern, caches of the leading layers, counters, enc)."""
+    x, positions = embed_inputs(params, batch, cfg)
+    enc_out = encode(params, batch, cfg) if cfg.enc_dec else None
+    lead = None
+    if cfg.n_dense_lead:
+        x, _, lead, _ = _run_stack(x, params["lead"], cfg, cfg.lead_pattern,
+                                   positions, causal=True,
+                                   collect_cache=collect_cache)
+    x, aux, caches, counts = _run_stack(
+        x, params["layers"], cfg, cfg.pattern, positions, causal=True,
+        enc_out=enc_out, collect_cache=collect_cache)
+    with jax.named_scope("lm_head"):
+        h = L.rmsnorm(x, params["final_ln"], cfg.rms_eps)
+    return h, aux, caches, lead, counts, enc_out
 
 
 def hidden_states(params: Params, batch: Dict[str, jnp.ndarray],
                   cfg: ArchConfig, collect_cache: bool = False):
     """Full forward to final hidden states. Returns (h, aux, caches, enc)."""
-    x, positions = embed_inputs(params, batch, cfg)
-    enc_out = encode(params, batch, cfg) if cfg.enc_dec else None
-    x, aux, caches = _run_stack(x, params["layers"], cfg, cfg.pattern,
-                                positions, causal=True, enc_out=enc_out,
-                                collect_cache=collect_cache)
-    with jax.named_scope("lm_head"):
-        h = L.rmsnorm(x, params["final_ln"])
+    h, aux, caches, _, _, enc_out = _forward(params, batch, cfg,
+                                             collect_cache)
     return h, aux, caches, enc_out
 
 
@@ -438,18 +542,23 @@ def _cache_seq_len(cfg: ArchConfig, mixer: str, max_len: int) -> int:
     return max_len
 
 
-def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Params:
-    """Zero decode cache: per period position, stacked over repeats."""
-    r = cfg.repeats
+def _cache_entries(cfg: ArchConfig, pattern, r: int, batch: int,
+                   max_len: int) -> Tuple[Params, ...]:
+    """Zero cache entries of one stack: per period position, stacked over
+    its ``r`` repeats."""
     dt = cfg.dtype
     layers = []
-    for mixer, _ in cfg.pattern:
+    for mixer, _ in pattern:
         entry: Dict[str, Any] = {}
         if mixer in ("attn", "swa"):
             c = _cache_seq_len(cfg, mixer, max_len)
             kv = (r, batch, c, cfg.n_kv_heads, cfg.head_dim)
             entry["k"] = jnp.zeros(kv, dt)
             entry["v"] = jnp.zeros(kv, dt)
+        elif mixer == "mla":
+            entry["c"] = jnp.zeros((r, batch, max_len, cfg.kv_lora_rank), dt)
+            entry["kr"] = jnp.zeros((r, batch, max_len,
+                                     cfg.qk_rope_head_dim), dt)
         elif mixer == "mamba":
             st = SSM.init_mamba_state(
                 batch, jax.tree.map(lambda x: x[0],
@@ -469,8 +578,20 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Params:
             entry["xk"] = jnp.zeros(kv, dt)
             entry["xv"] = jnp.zeros(kv, dt)
         layers.append(entry)
+    return tuple(layers)
+
+
+def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Params:
+    """Zero decode cache: per period position, stacked over repeats (the
+    leading layers' under ``lead``). Every layer leaf is (R, B, ...)."""
     # per-sequence positions: each batch slot may be at a different depth
-    return {"pos": jnp.zeros((batch,), jnp.int32), "layers": tuple(layers)}
+    cache = {"pos": jnp.zeros((batch,), jnp.int32),
+             "layers": _cache_entries(cfg, cfg.pattern, cfg.repeats, batch,
+                                      max_len)}
+    if cfg.n_dense_lead:
+        cache["lead"] = _cache_entries(cfg, cfg.lead_pattern,
+                                       cfg.n_dense_lead, batch, max_len)
+    return cache
 
 
 def _dummy_mamba_params(cfg: ArchConfig):
@@ -483,8 +604,10 @@ def _dummy_mamba_params(cfg: ArchConfig):
 def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
                   entry, i, pos):
     """One-token block of layer ``i``. x: (B,1,D); ``entry``: this period
-    position's cache, stacked over repeats. Returns (x, updated entry)."""
+    position's cache, stacked over repeats. Returns (x, updated entry,
+    counters or None)."""
     new = dict(entry)
+    counts = None
     at = partial(jax.lax.dynamic_index_in_dim, index=i, keepdims=False)
     if mixer in ("attn", "swa"):
         with jax.named_scope("attention"):
@@ -492,7 +615,7 @@ def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
             window = cfg.swa_window if mixer == "swa" else None
             ring = (mixer == "swa" and cfg.swa_window is not None
                     and entry["k"].shape[2] <= cfg.swa_window)
-            h = L.rmsnorm(x, p["mix"]["ln"])
+            h = L.rmsnorm(x, p["mix"]["ln"], cfg.rms_eps)
             q = L.dense(h, p["mix"]["wq"], p["mix"].get("bq")) \
                 .reshape(b, 1, cfg.n_heads, cfg.head_dim)
             k = L.dense(h, p["mix"]["wk"], p["mix"].get("bk")) \
@@ -515,6 +638,19 @@ def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
             else:
                 out = L.decode_attention(q, kc, vc, pos + 1, window=window)
             x = x + L.dense(out.reshape(b, 1, -1), p["mix"]["wo"])
+    elif mixer == "mla":
+        with jax.named_scope("attention"):
+            b = x.shape[0]
+            pp = jnp.broadcast_to(jnp.reshape(pos, (-1, 1))
+                                  if jnp.ndim(pos) else pos, (b, 1))
+            h = L.rmsnorm(x, p["mix"]["ln"], cfg.rms_eps)
+            q, c, k_pe = L.mla_project(h, p["mix"], cfg, pp)
+            with jax.named_scope("kv_update"):
+                new["c"], new["kr"] = L.update_kv_cache(
+                    entry["c"], entry["kr"], c, k_pe, i, pos)
+            out = L.mla_decode_attention(q, at(new["c"]), at(new["kr"]),
+                                         p["mix"]["wkv_b"], pos + 1, cfg)
+            x = x + L.dense(out.reshape(b, 1, -1), p["mix"]["wo"])
     elif mixer in ("mamba", "mlstm", "slstm"):
         key, state, block = {
             "mamba": ("ssm", SSM.MambaState, SSM.mamba_block),
@@ -527,59 +663,79 @@ def _decode_block(x, p, cfg: ArchConfig, mixer: str, ffn: str,
 
     if cfg.enc_dec and "cross" in p:
         b = x.shape[0]
-        h = L.rmsnorm(x, p["cross"]["ln"])
+        h = L.rmsnorm(x, p["cross"]["ln"], cfg.rms_eps)
         q = L.dense(h, p["cross"]["wq"]) \
             .reshape(b, 1, cfg.n_heads, cfg.head_dim)
         out = L.decode_attention(q, at(entry["xk"]), at(entry["xv"]),
                                  jnp.asarray(cfg.enc_seq, jnp.int32))
         x = x + L.dense(out.reshape(b, 1, -1), p["cross"]["wo"])
 
-    with jax.named_scope("mlp"):
-        if ffn == "moe":
-            x, _ = MOE.moe_block(x, p["ffn"], cfg)
-        elif ffn in ("mlp", "gelu"):
-            x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu")
-    return x, new
+    if ffn == "moe":
+        with jax.named_scope("moe"):
+            x, _, counts = MOE.moe_block(x, p["ffn"], cfg)
+    elif ffn in ("mlp", "gelu"):
+        with jax.named_scope("mlp"):
+            x = L.mlp(x, p["ffn"], "swiglu" if ffn == "mlp" else "gelu",
+                      cfg.rms_eps)
+    return x, new, counts
 
 
-def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
-                cfg: ArchConfig) -> Tuple[jnp.ndarray, Params]:
-    """One decode step. tokens: (B, 1) -> (logits (B, V), new cache)."""
-    pos = cache["pos"]
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+def _decode_stack(x, stack, entries, cfg: ArchConfig, pattern, pos):
+    """The one-token blocks of one stack. Returns (x, its new entries,
+    counters summed over its layers or None)."""
 
     def body(carry, xs):
-        x, entries = carry
+        x, entries, n = carry
         layer_params, i = xs
         new_entries = []
-        for pidx, (mixer, ffn) in enumerate(cfg.pattern):
-            x, new = _decode_block(x, layer_params[pidx], cfg, mixer, ffn,
-                                   entries[pidx], i, pos)
+        for pidx, (mixer, ffn) in enumerate(pattern):
+            x, new, n_i = _decode_block(x, layer_params[pidx], cfg, mixer,
+                                        ffn, entries[pidx], i, pos)
+            if n_i is not None:
+                n = n + n_i
             new_entries.append(new)
-        return (x, tuple(new_entries)), None
+        return (x, tuple(new_entries), n), None
 
     # the stacked cache rides in the carry and each layer indexes it by
     # ``i``, so a donated cache is written in place, one token per slot,
     # and no layer's cache is sliced out or stacked back. The scan stays
     # unscoped.
-    (x, new_layers), _ = jax.lax.scan(
-        body, (x, cache["layers"]),
-        (params["layers"], jnp.arange(cfg.repeats)))
+    repeats = jax.tree.leaves(entries)[0].shape[0]
+    (x, new, n), _ = jax.lax.scan(
+        body, (x, entries, _counts0(cfg, pattern)),
+        (stack, jnp.arange(repeats)))
+    return x, new, n
+
+
+def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
+                cfg: ArchConfig):
+    """One decode step. tokens: (B, 1) -> (logits (B, V), new cache), and
+    where ``cfg.counts_moe`` the MoE counters summed over the layers (an
+    int32 (3,), :data:`repro.models.moe.COUNTERS`)."""
+    pos = cache["pos"]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    new_cache = {}
+    if cfg.n_dense_lead:
+        x, new_cache["lead"], _ = _decode_stack(
+            x, params["lead"], cache["lead"], cfg, cfg.lead_pattern, pos)
+    x, new_layers, n = _decode_stack(x, params["layers"], cache["layers"],
+                                     cfg, cfg.pattern, pos)
     with jax.named_scope("lm_head"):
-        h = L.rmsnorm(x, params["final_ln"])
+        h = L.rmsnorm(x, params["final_ln"], cfg.rms_eps)
         logits = logits_last(params, h, cfg)
-    return logits, {"pos": pos + 1, "layers": new_layers}
+    new_cache.update({"pos": pos + 1, "layers": new_layers})
+    if cfg.counts_moe:
+        return logits, new_cache, n
+    return logits, new_cache
 
 
-def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ArchConfig,
-            max_len: int) -> Tuple[jnp.ndarray, Params]:
-    """Prefill: full forward, build a decode cache padded to ``max_len``."""
-    h, _, caches, enc_out = hidden_states(params, batch, cfg,
-                                          collect_cache=True)
-    s = h.shape[1]
+def _prefill_entries(caches, cfg: ArchConfig, pattern, s: int,
+                     max_len: int) -> Tuple[Params, ...]:
+    """A stack's prefill caches ((R, B, S, ...) per leaf) padded to
+    ``max_len``, or for a ring cache the last tokens, rotated."""
     layers = []
-    for pidx, (mixer, _) in enumerate(cfg.pattern):
+    for pidx, (mixer, _) in enumerate(pattern):
         entry = dict(caches[pidx]) if caches is not None else {}
         if mixer in ("attn", "swa"):
             with jax.named_scope("attention"), jax.named_scope("kv_update"):
@@ -596,9 +752,32 @@ def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ArchConfig,
                     idx = (jnp.arange(c) - shift) % c
                     entry["k"] = k[:, :, idx]
                     entry["v"] = v[:, :, idx]
+        elif mixer == "mla":
+            with jax.named_scope("attention"), jax.named_scope("kv_update"):
+                padw = ((0, 0), (0, 0), (0, max_len - s), (0, 0))
+                for name in ("c", "kr"):                       # (R,B,S,W)
+                    entry[name] = jnp.pad(entry[name], padw)
         layers.append(entry)
+    return tuple(layers)
+
+
+def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ArchConfig,
+            max_len: int):
+    """Prefill: full forward, build a decode cache padded to ``max_len``.
+    Returns (logits, cache), and the MoE counters as :func:`decode_step`
+    gives them."""
+    h, _, caches, lead, n, _ = _forward(params, batch, cfg,
+                                        collect_cache=True)
+    s = h.shape[1]
+    cache = {}
+    if cfg.n_dense_lead:
+        cache["lead"] = _prefill_entries(lead, cfg, cfg.lead_pattern, s,
+                                         max_len)
+    layers = _prefill_entries(caches, cfg, cfg.pattern, s, max_len)
     with jax.named_scope("lm_head"):
         logits = logits_last(params, h, cfg)
     b = h.shape[0]
-    return logits, {"pos": jnp.full((b,), s, jnp.int32),
-                    "layers": tuple(layers)}
+    cache.update({"pos": jnp.full((b,), s, jnp.int32), "layers": layers})
+    if cfg.counts_moe:
+        return logits, cache, n
+    return logits, cache

@@ -43,7 +43,7 @@ class _SpanHandle:
     span entry/exit only happens on instrumented (non-noop) runs."""
 
     __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0",
-                 "_ann")
+                 "_ann", "_ev")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  tid: Optional[str], args: Optional[dict]) -> None:
@@ -52,6 +52,7 @@ class _SpanHandle:
         self._cat = cat
         self._tid = tid
         self._args = args
+        self._ev: Optional[Dict[str, object]] = None
 
     def __enter__(self) -> "_SpanHandle":
         annotate = self._tracer.annotate
@@ -67,11 +68,22 @@ class _SpanHandle:
         dur = time.monotonic() - self._t0
         stack = self._tracer._stack()
         stack.pop()
-        self._tracer._record(self._name, self._cat, self._t0, dur,
-                             self._tid, self._args, depth=len(stack))
+        self._ev = self._tracer._record(self._name, self._cat, self._t0,
+                                        dur, self._tid, self._args,
+                                        depth=len(stack))
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
+
+    def set_args(self, **args) -> None:
+        """Add ``args`` to this span, open or closed: a value the span's
+        work yields only at a later sync.  A closed span's recorded event
+        gets them (an ``annotate`` context has already taken its args)."""
+        if self._args is None:
+            self._args = {}
+        self._args.update(args)
+        if self._ev is not None:
+            self._ev["args"] = self._args
 
 
 class Tracer:
@@ -150,7 +162,9 @@ class Tracer:
 
     def _record(self, name: str, cat: str, t_mono: float, dur_s: float,
                 tid: Optional[str], args: Optional[dict],
-                depth: int) -> None:
+                depth: int) -> Optional[Dict[str, object]]:
+        """Append one event; returns it, or None where sampling dropped
+        it."""
         ev: Dict[str, object] = {
             "name": name, "cat": cat, "ph": "X", "t": t_mono,
             "dur": dur_s,
@@ -167,9 +181,10 @@ class Tracer:
                         acc = self._dropped[cat] = [0, 0.0]
                     acc[0] += 1
                     acc[1] += dur_s
-                    return
+                    return None
                 self._kept[cat] = self._kept.get(cat, 0) + 1
             self._events.append(ev)
+        return ev
 
     def _stack(self) -> List[str]:
         stack = getattr(self._local, "stack", None)
@@ -248,6 +263,9 @@ class _NoopSpan:
 
     def __exit__(self, *exc) -> bool:
         return False
+
+    def set_args(self, **args) -> None:
+        pass
 
 
 _NOOP_SPAN = _NoopSpan()

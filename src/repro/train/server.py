@@ -24,6 +24,14 @@ Each ``step()`` emits ``repro.obs`` spans into the ambient tracer
 ``serve.best_effort``, ``serve.decode``, ``serve.decode_sync`` and
 ``serve.emit``).  The spans sit at the host syncs the step already has
 and add none; under the default no-op tracer each costs one call.
+
+A model whose expert layers count (``ArchConfig.counts_moe``) has its
+prefill and decode programs hand back the counters of
+:data:`repro.models.moe.COUNTERS`, summed over the layers; with the tracer
+enabled they are read at the sync that follows (``serve.first_token``,
+``serve.decode_sync``) and added to the args of the ``serve.prefill`` and
+``serve.decode`` spans as ``moe_<counter>`` (``set_args``).  An untraced
+step never reads them.
 """
 from __future__ import annotations
 
@@ -37,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.models import moe as MOE
 from repro.models import transformer as T
 
 # Request.status values, in lifecycle order.
@@ -68,6 +77,15 @@ class Request:
     @property
     def ok(self) -> bool:
         return self.status == DONE
+
+
+def _record_counts(tr, span, counts) -> None:
+    """With the tracer enabled, the program's MoE counters (``counts``: []
+    or [an int32 array of ``COUNTERS``]) as args of ``span``; the step has
+    synced already, so reading them waits for nothing."""
+    if tr.enabled and counts:
+        span.set_args(**dict(zip((f"moe_{k}" for k in MOE.COUNTERS),
+                                 np.asarray(counts[0]).tolist())))
 
 
 def _insert_slot(cache, req_cache, slot: int):
@@ -166,13 +184,14 @@ class Server:
                     batch["frames"] = jnp.zeros(
                         (1, self.cfg.enc_seq, self.cfg.d_model),
                         self.cfg.dtype)
-                with tr.span("serve.prefill"):
-                    logits, rc = self._prefill(self.params, batch)
+                with tr.span("serve.prefill") as prefill_span:
+                    logits, rc, *counts = self._prefill(self.params, batch)
                 with tr.span("serve.insert"):
                     self.cache = _insert_slot(self.cache, rc, slot)
                 with tr.span("serve.first_token"):
                     # also syncs the prefill
                     first = int(jnp.argmax(logits[0]))
+                    _record_counts(tr, prefill_span, counts)
                 req.prefill_s = time.perf_counter() - req.admit_s
             req.output = [first]
             req.status = ACTIVE
@@ -225,11 +244,12 @@ class Server:
             "active": len(self.active),
             "context": sum(len(r.prompt) + len(r.output)
                            for r in self.active.values())}
-        with tr.span("serve.decode", **args):
-            logits, self.cache = self._decode(
+        with tr.span("serve.decode", **args) as decode_span:
+            logits, self.cache, *counts = self._decode(
                 self.params, self.cache, jnp.asarray(self.last_tok))
         with tr.span("serve.decode_sync"):
             toks = np.asarray(jnp.argmax(logits, axis=-1))
+            _record_counts(tr, decode_span, counts)
         done: List[Request] = []
         with tr.span("serve.emit"):
             for slot, req in list(self.active.items()):

@@ -90,7 +90,8 @@ def _global_norm(tree):
 
 
 def serve_step_fn(cfg: T.ArchConfig) -> Callable:
-    """f(params, cache, tokens(B,1)) -> (logits (B,V), cache)."""
+    """f(params, cache, tokens(B,1)) -> (logits (B,V), cache), and the MoE
+    counters where ``cfg.counts_moe``."""
 
     def serve_decode(params, cache, tokens):
         return T.decode_step(params, cache, tokens, cfg)
@@ -158,7 +159,7 @@ def build_sharded_serve_step(cfg: T.ArchConfig, mesh: Mesh,
     step = serve_step_fn(cfg)
     jitted = jax.jit(step,
                      in_shardings=(p_sh, c_sh, tok_sh),
-                     out_shardings=(None, c_sh),
+                     out_shardings=(None, c_sh) + (None,) * cfg.counts_moe,
                      donate_argnums=(1,))
     return jitted, {"params": p_sh, "cache": c_sh}
 

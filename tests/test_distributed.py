@@ -354,7 +354,7 @@ def test_recommended_rules_policy():
     from repro.dist.sharding import ShardingRules
     on = ("qwen2-1.5b", "minitron-4b", "smollm-360m", "qwen1.5-4b",
           "internvl2-26b", "whisper-base")
-    off = ("mixtral-8x22b", "moonshot-v1-16b-a3b", "xlstm-1.3b",
+    off = ("mixtral-8x22b", "moonlight-16b-a3b", "xlstm-1.3b",
            "jamba-1.5-large-398b")
     for a in on:
         assert ShardingRules.recommended(get_config(a)).sequence_parallel, a

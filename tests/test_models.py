@@ -45,10 +45,11 @@ def test_reduced_prefill_decode(arch):
     rng = jax.random.PRNGKey(0)
     params = T.init_params(rng, cfg)
     batch = {k: v for k, v in _batch_for(cfg, rng).items() if k != "labels"}
-    logits, cache = T.prefill(params, batch, cfg, max_len=64)
+    # a model whose experts count hands its counters back third
+    logits, cache, *_ = T.prefill(params, batch, cfg, max_len=64)
     assert logits.shape == (2, cfg.vocab)
     tok = jnp.argmax(logits, -1)[:, None]
-    logits2, cache2 = T.decode_step(params, cache, tok, cfg)
+    logits2, cache2, *_ = T.decode_step(params, cache, tok, cfg)
     assert logits2.shape == (2, cfg.vocab)
     assert bool(jnp.isfinite(logits2).all())
     assert bool((cache2["pos"] == cache["pos"] + 1).all())
@@ -110,8 +111,8 @@ def _decode_by_layer(params, cache, tokens, cfg):
         for pidx, (mixer, ffn) in enumerate(cfg.pattern):
             p = jax.tree.map(lambda a: a[r], params["layers"][pidx])
             entry = jax.tree.map(lambda a: a[r:r + 1], cache["layers"][pidx])
-            x, new = T._decode_block(x, p, cfg, mixer, ffn, entry, 0,
-                                     cache["pos"])
+            x, new, _ = T._decode_block(x, p, cfg, mixer, ffn, entry, 0,
+                                        cache["pos"])
             row.append(new)
         rows.append(row)
     layers = tuple(jax.tree.map(lambda *a: jnp.concatenate(a),
@@ -176,9 +177,8 @@ def test_param_counts_full_configs():
         "qwen1.5-4b": (3e9, 5e9),
         "minitron-4b": (3.4e9, 5.8e9),
         "mixtral-8x22b": (1.2e11, 1.6e11),
-        # the assigned 48L x 64e x d_ff=1408 config totals ~28B with ~4B
-        # active (a3b-class active size; see DESIGN.md)
-        "moonshot-v1-16b-a3b": (2.4e10, 3.2e10),
+        # published: 15.96B in all, 2.91B active per token
+        "moonlight-16b-a3b": (1.5e10, 1.7e10),
         "jamba-1.5-large-398b": (3.2e11, 4.6e11),
         "xlstm-1.3b": (0.9e9, 1.8e9),
         "internvl2-26b": (1.5e10, 2.6e10),  # backbone only (no ViT)

@@ -51,7 +51,7 @@ def test_kv_cache_bytes_swa_ring():
 
 
 def test_memory_traffic_decode_dominated_by_weights_or_cache():
-    cfg = get_config("moonshot-v1-16b-a3b")
+    cfg = get_config("moonlight-16b-a3b")
     mesh = {"data": 16, "model": 16}
     m = RL.memory_traffic(cfg, "decode", 32768, 128, mesh)
     assert m > 0

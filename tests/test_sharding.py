@@ -21,7 +21,7 @@ from repro.train import steps as ST
 # §Perf policy: SP on for pure-attention stacks, off for MoE / recurrent.
 SP_ON = {"qwen2-1.5b", "minitron-4b", "smollm-360m", "qwen1.5-4b",
          "internvl2-26b", "whisper-base"}
-SP_OFF = {"mixtral-8x22b", "moonshot-v1-16b-a3b", "xlstm-1.3b",
+SP_OFF = {"mixtral-8x22b", "moonlight-16b-a3b", "xlstm-1.3b",
           "jamba-1.5-large-398b"}
 
 
