@@ -6,6 +6,12 @@ A fixed decode batch of ``n_slots``; requests are prefilled individually
 together.  Finished slots free immediately and new requests join without
 draining the batch.
 
+The insert is one jitted program, ``serve_insert``, beside
+``serve_prefill`` and ``serve_decode``: it donates the batched cache and
+takes the slot as a traced scalar, so one compile serves every slot and
+XLA writes the request's slot in place.  Admission therefore holds one
+batched cache, not two, and moves one slot's bytes, not the whole cache's.
+
 Latency accounting is end-to-end: ``Request.latency_s`` runs from
 ``submit()`` to finish, with a ``queue_s`` / ``prefill_s`` / ``decode_s``
 breakdown per request — an SLA on p99 latency is meaningless if queue wait
@@ -88,8 +94,9 @@ def _record_counts(tr, span, counts) -> None:
                                  np.asarray(counts[0]).tolist())))
 
 
-def _insert_slot(cache, req_cache, slot: int):
-    """Copy a single-request cache into batch slot ``slot``."""
+def _insert_slot(cache, req_cache, slot):
+    """Copy a single-request cache into batch slot ``slot`` (an int or a
+    traced int32 scalar); the pure function ``serve_insert`` jits."""
 
     def ins(batched, single):
         if batched.ndim == 1:        # pos: (B,)
@@ -135,8 +142,12 @@ class Server:
         def serve_prefill(p, b):
             return T.prefill(p, b, cfg, max_len)
 
+        def serve_insert(c, rc, slot):
+            return _insert_slot(c, rc, slot)
+
         self._decode = decode_fn or jax.jit(serve_decode, donate_argnums=(1,))
         self._prefill = jax.jit(serve_prefill)
+        self._insert = jax.jit(serve_insert, donate_argnums=(0,))
 
     def prefill_programs(self) -> int:
         """Prefill programs compiled so far: prefill is jitted on the exact
@@ -187,7 +198,8 @@ class Server:
                 with tr.span("serve.prefill") as prefill_span:
                     logits, rc, *counts = self._prefill(self.params, batch)
                 with tr.span("serve.insert"):
-                    self.cache = _insert_slot(self.cache, rc, slot)
+                    self.cache = self._insert(self.cache, rc,
+                                              np.int32(slot))
                 with tr.span("serve.first_token"):
                     # also syncs the prefill
                     first = int(jnp.argmax(logits[0]))

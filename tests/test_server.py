@@ -1,7 +1,9 @@
 """Serving-stack regression tests: latency accounting, graceful
 rejection, termination modes, slot reuse, interleaved-admission parity,
 and abandoned-request marking — the serving bugfixes of the online-tuning
-PR, pinned down.
+PR, pinned down — and the jitted slot insert (``serve_insert``) against
+the eager ``_insert_slot`` it wraps, on a dense and a latent-attention
+MoE config.
 
 One reduced model + one shared jitted decode function for the whole
 module (every ``Server`` re-jitting its own decode would dominate the
@@ -14,7 +16,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import transformer as T
 from repro.train.server import (ABANDONED, DONE, QUEUED, REJECTED, Request,
-                                Server)
+                                Server, _insert_slot)
 
 
 @pytest.fixture(scope="module")
@@ -146,3 +148,91 @@ def test_abandoned_requests_marked_loudly(setup, srv):
     ok = srv.submit(Request(uid=141, prompt=_prompt(cfg, 5),
                             max_new_tokens=3))
     assert srv.run_until_drained() == [ok] and ok.status == DONE
+
+
+# ---------------------------------------------------------------- insert
+INSERT_ARCHS = ["qwen2-1.5b", "moonlight-16b-a3b"]   # dense; MLA + ``lead``
+
+
+@pytest.fixture(scope="module", params=INSERT_ARCHS)
+def arch_setup(request):
+    cfg = get_config(request.param, reduced=True).with_(
+        dtype=jnp.float32, param_dtype=jnp.float32, remat=False)
+    return cfg, T.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _filled_cache(cfg, n_slots, max_len, seed=0):
+    """A batched cache with every leaf random, so that a write outside
+    the slot would show."""
+    rng = np.random.default_rng(seed)
+
+    def fill(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(0, max_len, a.shape), a.dtype)
+        return jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+
+    return jax.tree.map(fill, T.init_cache(cfg, n_slots, max_len))
+
+
+def _req_cache(srv, cfg, n=7):
+    _, rc, *_ = srv._prefill(srv.params,
+                             {"tokens": jnp.asarray(_prompt(cfg, n)[None])})
+    return rc
+
+
+@pytest.mark.parametrize("slot", ["first", "last"])
+def test_jitted_insert_matches_eager(arch_setup, slot):
+    cfg, params = arch_setup
+    srv = Server(params, cfg, n_slots=3, max_len=32)
+    slot = 0 if slot == "first" else srv.n_slots - 1
+    cache, rc = _filled_cache(cfg, 3, 32), _req_cache(srv, cfg)
+    want = jax.tree.map(np.asarray, _insert_slot(cache, rc, slot))
+    got = srv._insert(cache, rc, np.int32(slot))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    assert "pos" in got and (cfg.n_dense_lead == 0) == ("lead" not in got)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
+def test_insert_donates_the_batched_cache(arch_setup):
+    cfg, params = arch_setup
+    srv = Server(params, cfg, n_slots=3, max_len=32)
+    old, rc = _filled_cache(cfg, 3, 32), _req_cache(srv, cfg)
+    new = srv._insert(old, rc, np.int32(1))
+    jax.block_until_ready(new)
+    assert all(a.is_deleted() for a in jax.tree.leaves(old))
+    assert not any(a.is_deleted() for a in jax.tree.leaves(rc))
+
+
+def test_insert_compiles_once_for_every_slot(arch_setup):
+    cfg, params = arch_setup
+    srv = Server(params, cfg, n_slots=3, max_len=32)
+    for i in range(3):
+        srv.submit(Request(uid=i, prompt=_prompt(cfg, 5 + 2 * i, seed=i),
+                           max_new_tokens=3))
+    srv.step()
+    assert sorted(srv.active) == [0, 1, 2]
+    assert srv._insert._cache_size() == 1
+    assert len(srv.run_until_drained()) == 3
+
+
+def test_moe_server_matches_one_slot_server():
+    """Latent-attention MoE served in three slots gives each request the
+    tokens a one-slot server gives it alone."""
+    cfg = get_config("moonlight-16b-a3b", reduced=True).with_(
+        dtype=jnp.float32, param_dtype=jnp.float32, remat=False)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    reqs = [Request(uid=i, prompt=_prompt(cfg, 4 + 3 * i, seed=20 + i),
+                    max_new_tokens=3 + i) for i in range(4)]
+    srv = Server(params, cfg, n_slots=3, max_len=32)
+    for r in reqs:
+        srv.submit(r)
+    batched = {r.uid: r.output for r in srv.run_until_drained()}
+    assert len(batched) == 4
+    solo = Server(params, cfg, n_slots=1, max_len=32)
+    for r in reqs:
+        alone = solo.submit(Request(uid=r.uid, prompt=r.prompt,
+                                    max_new_tokens=r.max_new_tokens))
+        solo.run_until_drained()
+        assert alone.output == batched[r.uid], r.uid
