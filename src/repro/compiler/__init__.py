@@ -36,15 +36,6 @@ _EXPORTS = {
     "NetworkTask": "repro.compiler.zoo",
     "get_network": "repro.compiler.zoo",
     "network_names": "repro.compiler.zoo",
-    "IdleSlotExecutor": "repro.compiler.serve_tune",
-    "LiveServeHost": "repro.compiler.serve_tune",
-    "ServeModel": "repro.compiler.serve_tune",
-    "ServeReport": "repro.compiler.serve_tune",
-    "ServeSLA": "repro.compiler.serve_tune",
-    "SimServeHost": "repro.compiler.serve_tune",
-    "TraceConfig": "repro.compiler.serve_tune",
-    "synthetic_trace": "repro.compiler.serve_tune",
-    "tune_while_serving": "repro.compiler.serve_tune",
 }
 __all__ = sorted(_EXPORTS)
 

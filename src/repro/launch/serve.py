@@ -1,19 +1,13 @@
-"""Serving launcher: batched continuous decoding, optionally with an
-online tuning session measuring candidate ShardSpace geometries on idle
-decode slots (``--autotune``, see :mod:`repro.compiler.serve_tune`).
+"""Serving launcher: batched continuous decoding of synthetic requests.
 
-    # plain serving of synthetic requests
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
         --requests 8 --slots 4
 
-    # timed Poisson arrivals + online tuning under a 500 ms p99 SLA
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
-        --requests 64 --rate 20 --autotune --budget 24 --sla-ms 500
-
-``--rate 0`` (default) submits every request up front — the drain-the-batch
-mode the launcher always had.  With ``--rate`` the trace replays Poisson
-arrivals against the wall clock (idle gaps fast-forwarded), which is what
-gives ``--autotune`` idle windows to measure in.
+Every request is submitted up front and the server runs until drained;
+``violation_pct`` is the share of finished requests whose end-to-end
+latency exceeds ``--sla-ms``.  Open-loop serving (timed arrivals against
+the wall clock) is the benchmark's: ``python3 bench/run.py`` and
+``bench/sweep.py``.
 
 Throughput excludes jit warm-up: one throwaway request is served before
 the timed run so the first-step compile doesn't pollute ``tokens_per_sec``.
@@ -66,7 +60,7 @@ def _warm_up(srv: Server, vocab: int) -> None:
 def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description="continuous-batching LM server over synthetic "
-                    "requests, with optional online geometry tuning")
+                    "requests")
     ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
@@ -74,22 +68,9 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--rate", type=float, default=0.0, metavar="REQ_PER_S",
-                    help="Poisson arrival rate; 0 = submit everything up "
-                         "front (legacy drain mode)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="run an online tuning session on idle decode "
-                         "slots while serving (needs --rate > 0)")
-    ap.add_argument("--budget", type=int, default=24,
-                    help="measurements per tuned cell (--autotune)")
     ap.add_argument("--sla-ms", type=float, default=500.0,
-                    help="p99 end-to-end latency SLA in milliseconds")
-    ap.add_argument("--records", metavar="PATH", default=None,
-                    help="JSONL measurement records for warm resume "
-                         "(--autotune)")
-    ap.add_argument("--monitor", type=int, default=None, metavar="PORT",
-                    help="live /metrics + /status + /trace on this port "
-                         "for the duration of the run (0 = ephemeral)")
+                    help="end-to-end latency SLA in milliseconds; "
+                         "violation_pct counts requests over it")
     ap.add_argument("--json-out", metavar="PATH", default=None,
                     help="also write the report JSON here")
     return ap
@@ -140,56 +121,11 @@ def serve_drain(srv: Server, cfg: T.ArchConfig, args,
 
 
 def main(argv: Optional[Sequence[str]] = None):
-    ap = make_parser()
-    args = ap.parse_args(argv)
-    if args.autotune and args.rate <= 0:
-        ap.error("--autotune needs --rate > 0: tuning measures in the "
-                 "idle gaps between arrivals, and a fully up-front queue "
-                 "has none")
-
+    args = make_parser().parse_args(argv)
     enable_compile_cache()
     cfg, srv = build_server(args)
-
     doc = {"arch": cfg.name, "sla_ms": args.sla_ms}
-    if args.autotune or args.rate > 0:
-        from repro.compiler.serve_tune import (LiveServeHost, ServeModel,
-                                               ServeSLA, TraceConfig,
-                                               tune_while_serving)
-        trace = TraceConfig(
-            n_requests=args.requests, rate_per_s=args.rate,
-            prompt_len=(4, max(args.max_len // 4, 5)),
-            max_new=(2, args.max_new), seed=args.seed)
-        host = LiveServeHost(
-            srv, trace, sla=ServeSLA(target_s=args.sla_ms / 1e3),
-            model=ServeModel(arch=args.arch), vocab=cfg.vocab,
-            seed=args.seed)
-        if args.autotune:
-            rep = tune_while_serving(
-                host, budget=args.budget, records=args.records,
-                monitor=args.monitor, seed=args.seed,
-                offline_compare=False)
-            doc["autotune"] = {
-                "budget": rep.budget,
-                "online": rep.online,
-                "measurements": rep.serve["measurements"],
-                "preempted": rep.serve["preempted"],
-            }
-        else:
-            host.finish_serving()
-        summary = host.summary()
-        done = host.done
-        doc.update({
-            "requests": summary["served"],
-            "generated_tokens": int(sum(len(r.output) for r in done)),
-            "wall_s": round(summary["sim_time_s"], 3),
-            "tokens_per_sec": round(summary["tokens_per_sec"] or 0.0, 1),
-            "violation_pct": round(summary["violation_pct"] or 0.0, 3),
-            "rejected": summary["rejected"],
-            "abandoned": summary["abandoned"],
-        })
-        doc.update(_latency_stats(done))
-    else:
-        doc.update(serve_drain(srv, cfg, args)[0])
+    doc.update(serve_drain(srv, cfg, args)[0])
 
     # loud, unmissable: these were never served and are NOT in the stats
     for kind, reqs in (("rejected", srv.rejected),

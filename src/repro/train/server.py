@@ -18,16 +18,10 @@ breakdown per request — an SLA on p99 latency is meaningless if queue wait
 and prefill are invisible, which is exactly what the pre-fix timer (started
 after prefill, at admission) got wrong.
 
-Idle capacity is a first-class resource: a ``best_effort`` hook (one small
-chunk of background work per call — e.g. one candidate measurement of an
-online tuning session, see :mod:`repro.compiler.serve_tune`) runs only when
-the queue is empty and at least one decode slot is free, so live requests
-always preempt background work at chunk granularity.
-
 Each ``step()`` emits ``repro.obs`` spans into the ambient tracer
 (``serve.step`` and, inside it, ``serve.admit`` per request with its
-``serve.prefill`` / ``serve.insert`` / ``serve.first_token``, then
-``serve.best_effort``, ``serve.decode``, ``serve.decode_sync`` and
+``serve.prefill`` / ``serve.insert`` / ``serve.first_token``, then, when
+a slot is active, ``serve.decode``, ``serve.decode_sync`` and
 ``serve.emit``).  The spans sit at the host syncs the step already has
 and add none; under the default no-op tracer each costs one call.
 
@@ -44,7 +38,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Callable, Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -109,21 +103,11 @@ def _insert_slot(cache, req_cache, slot):
 
 
 class Server:
-    """Continuous-batching server; see the module docstring.
-
-    ``best_effort`` is an optional callable ``(server) -> bool`` invoked
-    from :meth:`step` whenever there is idle capacity (queue empty AND at
-    least one free slot).  It must do at most one *small* chunk of work
-    per call and return True if it did any — the server never calls it
-    while requests wait, which is the admission-aware preemption contract
-    background measurement schedulers rely on.
-    """
+    """Continuous-batching server; see the module docstring."""
 
     def __init__(self, params, cfg: T.ArchConfig, n_slots: int = 4,
                  max_len: int = 512,
-                 decode_fn: Optional[Callable] = None,
-                 greedy: bool = True,
-                 best_effort: Optional[Callable[["Server"], bool]] = None):
+                 decode_fn: Optional[Callable] = None):
         self.params, self.cfg = params, cfg
         self.n_slots, self.max_len = n_slots, max_len
         self.cache = T.init_cache(cfg, n_slots, max_len)
@@ -134,7 +118,6 @@ class Server:
         self.queue: Deque[Request] = deque()
         self.rejected: List[Request] = []
         self.abandoned: List[Request] = []
-        self.best_effort = best_effort
 
         def serve_decode(p, c, t):
             return T.decode_step(p, c, t, cfg)
@@ -211,18 +194,6 @@ class Server:
             self.active[slot] = req
             self.new_counts[slot] = 1
 
-    # ---------------------------------------------------------- idle work
-    def idle_capacity(self) -> int:
-        """Free decode slots available for best-effort work right now —
-        zero whenever any request is waiting for admission (live traffic
-        preempts background measurements)."""
-        return 0 if self.queue else len(self.free)
-
-    def _tick_best_effort(self) -> bool:
-        if self.best_effort is None or not self.idle_capacity():
-            return False
-        return bool(self.best_effort(self))
-
     # ------------------------------------------------------------- decode
     def _finish(self, slot: int, status: str = DONE) -> Request:
         req = self.active.pop(slot)
@@ -237,18 +208,14 @@ class Server:
         return req
 
     def step(self) -> List[Request]:
-        """One decode step for all active slots; returns finished requests.
-        With idle capacity (free slots + empty queue) one chunk of
-        best-effort work runs first — alongside the decode when other
-        slots are busy, or alone when the server is idle."""
+        """Admit what the free slots take, then one decode step for all
+        active slots; returns finished requests."""
         tr = obs.current()
         with tr.span("serve.step"):
             return self._step(tr)
 
     def _step(self, tr) -> List[Request]:
         self._admit(tr)
-        with tr.span("serve.best_effort"):
-            self._tick_best_effort()
         if not self.active:
             return []
         # the slots decoded and the positions they attend, summed

@@ -1,9 +1,9 @@
 """Serving-stack regression tests: latency accounting, graceful
 rejection, termination modes, slot reuse, interleaved-admission parity,
-and abandoned-request marking — the serving bugfixes of the online-tuning
-PR, pinned down — and the jitted slot insert (``serve_insert``) against
-the eager ``_insert_slot`` it wraps, on a dense and a latent-attention
-MoE config.
+abandoned-request marking, admission bounded by the free slots and an
+idle step that dispatches nothing — and the jitted slot insert
+(``serve_insert``) against the eager ``_insert_slot`` it wraps, on a dense
+and a latent-attention MoE config.
 
 One reduced model + one shared jitted decode function for the whole
 module (every ``Server`` re-jitting its own decode would dominate the
@@ -13,10 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config
 from repro.models import transformer as T
-from repro.train.server import (ABANDONED, DONE, QUEUED, REJECTED, Request,
-                                Server, _insert_slot)
+from repro.train.server import (ABANDONED, ACTIVE, DONE, QUEUED, REJECTED,
+                                Request, Server, _insert_slot)
 
 
 @pytest.fixture(scope="module")
@@ -148,6 +149,38 @@ def test_abandoned_requests_marked_loudly(setup, srv):
     ok = srv.submit(Request(uid=141, prompt=_prompt(cfg, 5),
                             max_new_tokens=3))
     assert srv.run_until_drained() == [ok] and ok.status == DONE
+
+
+def test_admission_takes_only_free_slots(setup, srv):
+    cfg, _, _ = setup
+    reqs = [srv.submit(Request(uid=150 + i, prompt=_prompt(cfg, 5, seed=i),
+                               max_new_tokens=3)) for i in range(5)]
+    srv.step()
+    assert [r.status for r in reqs] == [ACTIVE] * 2 + [QUEUED] * 3
+    assert sorted(r.uid for r in srv.active.values()) == [150, 151]
+    assert all(r.admit_s is None for r in reqs[2:])
+    assert list(srv.queue) == reqs[2:] and not srv.free
+    assert len(srv.run_until_drained()) == 5
+
+
+def test_idle_step_dispatches_nothing(setup):
+    cfg, params, decode = setup
+    calls = []
+
+    def counting(*a):
+        calls.append(1)
+        return decode(*a)
+
+    idle = Server(params, cfg, n_slots=2, max_len=64, decode_fn=counting)
+    tracer = obs.Tracer()
+    with obs.use(tracer):
+        assert idle.step() == []
+    assert calls == []
+    assert [s["name"] for s in tracer.spans()] == ["serve.step"]
+    # the wrapper is the one the server decodes through
+    idle.submit(Request(uid=160, prompt=_prompt(cfg, 5), max_new_tokens=3))
+    idle.step()
+    assert len(calls) == 1
 
 
 # ---------------------------------------------------------------- insert

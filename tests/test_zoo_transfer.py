@@ -356,8 +356,7 @@ def test_committed_bench_artifacts_are_valid():
     tr = _load_benchmarks("tuning_runs")
     paths = sorted(glob.glob(os.path.join(ROOT, "BENCH_*.json")))
     assert {os.path.basename(p) for p in paths} >= \
-        {"BENCH_netopt.json", "BENCH_transfer.json", "BENCH_hetero.json",
-         "BENCH_serve.json"}
+        {"BENCH_netopt.json", "BENCH_transfer.json", "BENCH_hetero.json"}
     for p in paths:
         doc = tr.validate_bench_doc(json.load(open(p)))
         assert doc["git_rev"] != "unknown", p
@@ -378,33 +377,6 @@ def test_hetero_bench_artifact_shows_pipeline_win():
     # the pipeline cut is interior (a real 2-stage partition, not a
     # degenerate everything-on-one-chip split)
     assert 0 < m["k2_cut"] < 12
-
-
-def test_serve_bench_artifact_shows_online_tuning_win():
-    """The committed BENCH_serve.json must demonstrate the
-    tuning-as-a-service headline: on the synthetic million-request trace
-    the online search converged to within 10% of the offline-tuned
-    geometry, p99-SLA violations stayed under 3%, and the post-tuning
-    phase beats the default-geometry baseline on both p99 latency and
-    tokens/sec — with end-to-end (queue + prefill + decode) latency
-    accounting."""
-    with open(os.path.join(ROOT, "BENCH_serve.json")) as f:
-        doc = json.load(f)
-    m = doc["metrics"]
-    assert m["served_requests"] >= 1_000_000
-    assert m["online_offline_min_ratio"] >= 0.9
-    assert m["sla_violation_pct"] < 3.0
-    assert m["after_p99_latency_s"] < m["before_p99_latency_s"]
-    assert m["after_tokens_per_sec"] > m["before_tokens_per_sec"]
-    assert m["throughput_gain_x"] > 1.0
-    # measurements ran as best-effort work: some were preempted by live
-    # traffic, and the idle time they consumed is accounted
-    assert m["measurements"] > 0 and m["measurements_preempted"] > 0
-    assert m["measure_idle_s"] > 0
-    # end-to-end accounting: queue wait is visible in the latency numbers
-    # (p99 before tuning reflects burst queueing, not just decode time)
-    assert m["mean_queue_s"] > 0
-    assert m["before_p99_latency_s"] > 50 * m["online_decode_step_s"]
 
 
 def test_transfer_bench_artifact_shows_transfer_win():

@@ -42,7 +42,6 @@ REQUIRED = (
     "repro.compiler.oracle",
     "repro.compiler.records",
     "repro.compiler.report",
-    "repro.compiler.serve_tune",
     "repro.compiler.session",
     "repro.compiler.surrogate_store",
     "repro.compiler.task",
